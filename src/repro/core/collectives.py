@@ -70,6 +70,25 @@ def _pad_to(x: jax.Array, multiple: int) -> tuple[jax.Array, int]:
     return flat, pad
 
 
+# The TPU's lane width.  Payloads are indexed chunk by chunk below; viewed as
+# [rows, 128] a chunk sits on the chip's native (8, 128) tiles.  As a
+# [S, L/S] view of a reshaped multi-dimensional gradient instead, the TPU
+# compiler lowers the dynamic chunk indexing into a relayout whose compile
+# time grows with the payload (~40 s for one 110 MB bucket on v5e).
+_LANES = 128
+
+
+def _lane_shape(n: int) -> tuple[int, ...]:
+    """``n`` elements as [n/128, 128] when 128 divides ``n``, else [n]."""
+    return (n // _LANES, _LANES) if n % _LANES == 0 else (n,)
+
+
+def _chunk_view(flat: jax.Array, s: int) -> jax.Array:
+    """The padded 1-D payload's ``s`` chunks as rows: row ``i`` is chunk
+    ``i`` in lane layout (the element order of ``flat`` is unchanged)."""
+    return flat.reshape(s, *_lane_shape(flat.shape[0] // s))
+
+
 def _unpad(flat: jax.Array, pad: int, shape: tuple[int, ...]) -> jax.Array:
     if pad:
         flat = flat[: flat.shape[0] - pad]
@@ -95,7 +114,7 @@ def allreduce_ring(x: jax.Array, axis_name: str, axis_size: int) -> jax.Array:
         return x
     shape = x.shape
     flat, pad = _pad_to(x, s)
-    chunks = flat.reshape(s, -1)  # [S, L/S]
+    chunks = _chunk_view(flat, s)  # [S, L/S] in lane layout
     idx = lax.axis_index(axis_name)
     perm = _shift_perm(s)
 
@@ -126,7 +145,7 @@ def reduce_scatter_ring(x: jax.Array, axis_name: str, axis_size: int) -> jax.Arr
     if s == 1:
         return x.reshape(-1)
     flat, _ = _pad_to(x, s)
-    chunks = flat.reshape(s, -1)
+    chunks = _chunk_view(flat, s)
     idx = lax.axis_index(axis_name)
     perm = _shift_perm(s)
 
@@ -137,7 +156,7 @@ def reduce_scatter_ring(x: jax.Array, axis_name: str, axis_size: int) -> jax.Arr
     for t in range(1, s):
         recv = lax.ppermute(send, axis_name, perm)
         send = recv + chunk(idx + s - 1 - t)
-    return send
+    return send.reshape(-1)
 
 
 def all_gather_ring(shard: jax.Array, axis_name: str, axis_size: int) -> jax.Array:
@@ -151,9 +170,9 @@ def all_gather_ring(shard: jax.Array, axis_name: str, axis_size: int) -> jax.Arr
         return flat
     idx = lax.axis_index(axis_name)
     perm = _shift_perm(s)
-    out = jnp.zeros((s, flat.shape[0]), flat.dtype)
-    out = lax.dynamic_update_index_in_dim(out, flat, idx % s, axis=0)
-    cur = flat
+    cur = flat.reshape(_lane_shape(flat.shape[0]))
+    out = jnp.zeros((s, *cur.shape), flat.dtype)
+    out = lax.dynamic_update_index_in_dim(out, cur, idx % s, axis=0)
     for t in range(1, s):
         cur = lax.ppermute(cur, axis_name, perm)
         out = lax.dynamic_update_index_in_dim(out, cur, (idx - t) % s, axis=0)
@@ -230,9 +249,8 @@ def reduce_scatter_alltoall(x: jax.Array, axis_name: str,
     if s == 1:
         return x.reshape(-1)
     flat, _ = _pad_to(x, s)
-    chunks = flat.reshape(s, -1)
-    recv = alltoall_ppermute(chunks, axis_name, s)
-    return recv.sum(axis=0)
+    recv = alltoall_ppermute(_chunk_view(flat, s), axis_name, s)
+    return recv.sum(axis=0).reshape(-1)
 
 
 def all_gather_alltoall(shard: jax.Array, axis_name: str,
@@ -244,7 +262,8 @@ def all_gather_alltoall(shard: jax.Array, axis_name: str,
     flat = shard.reshape(-1)
     if s == 1:
         return flat
-    msgs = jnp.tile(flat[None], (s, 1))
+    lanes = flat.reshape(_lane_shape(flat.shape[0]))
+    msgs = jnp.broadcast_to(lanes[None], (s, *lanes.shape))
     recv = alltoall_ppermute(msgs, axis_name, s)
     return recv.reshape(-1)
 

@@ -157,7 +157,7 @@ def ef_compress_blocks(
     bits: int = 8,
     block: int = 1024,
     fused: bool = False,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Per-block-scale error-feedback compression of one flat bucket.
 
@@ -168,7 +168,8 @@ def ef_compress_blocks(
     ``new_residual = target - deq`` feeds the next step's EF accumulator.
 
     ``fused=True`` routes through the pallas quantize+bucketize kernel
-    (``kernels.ops.ef_quantize_bucketize``); the jnp path below is the
+    (``kernels.ops.ef_quantize_bucketize``, compiled for the TPU unless the
+    caller passes ``interpret=True``); the jnp path below is the
     bit-exact fallback and the kernel's oracle shape.  ``bits >= 32`` is the
     identity (no compression, residual zero).
     """
@@ -177,7 +178,10 @@ def ef_compress_blocks(
     if fused:
         from ..kernels import ops as kops
 
-        _q, _s, deq, new_r, n = kops.ef_quantize_bucketize(
+        # the length is sliced back statically: the jitted wrapper returns
+        # its ``n`` as an array, which cannot bound a slice under a trace
+        n = flat.shape[0]
+        _q, _s, deq, new_r, _ = kops.ef_quantize_bucketize(
             flat, residual, block=block, bits=bits, interpret=interpret)
         return deq[:n].astype(flat.dtype), new_r[:n].astype(residual.dtype)
     qmax = float(2 ** (bits - 1) - 1)

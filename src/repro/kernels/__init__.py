@@ -4,5 +4,6 @@
 #   rmsnorm           fused row-blocked RMSNorm
 #   mamba_scan        Mamba2 SSD intra-chunk compute + carried state
 #   quant             int8 block quantize / fused dequant-add (compressed sync)
-# Kernels are TPU targets; on CPU (this container) ops.py runs interpret=True
-# and tests/test_kernels.py sweeps shapes/dtypes against the oracles.
+# Kernels are TPU targets.  The CPU tests pass interpret=True explicitly and
+# sweep shapes/dtypes against the oracles; tests/test_chip_compile.py compiles
+# the quant kernels for a described v5e chip.

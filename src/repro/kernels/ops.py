@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU —
-the kernels are TPU-target artifacts validated here in interpret mode
-against ``ref.py`` (tests sweep shapes and dtypes).
+The kernels compile for the TPU.  ``interpret=True`` runs them in the Pallas
+interpreter instead; only a caller that wants that asks for it (the CPU
+tests, which check every kernel against ``ref.py``).  Nothing here picks the
+mode from the backend, so a kernel never falls back to the interpreter
+silently, and a compile for a described TPU topology sees the real kernel.
 """
 
 from __future__ import annotations
@@ -18,15 +20,10 @@ from . import quant as _q
 from . import rmsnorm as _rn
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("causal", "q_block", "kv_block", "interpret"))
 def flash_attention(q, k, v, *, causal=True, q_block=256, kv_block=256,
-                    interpret=None):
+                    interpret=False):
     """q [B,Sq,H,D]; k/v [B,Skv,K,D] (GQA: K | H).  Returns [B,Sq,H,D]."""
-    interpret = _default_interpret() if interpret is None else interpret
     b, sq, h, d = q.shape
     kh = k.shape[2]
     g = h // kh
@@ -43,15 +40,13 @@ def flash_attention(q, k, v, *, causal=True, q_block=256, kv_block=256,
 
 
 @partial(jax.jit, static_argnames=("eps", "rows_block", "interpret"))
-def rmsnorm(x, w, *, eps=1e-6, rows_block=256, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+def rmsnorm(x, w, *, eps=1e-6, rows_block=256, interpret=False):
     return _rn.rmsnorm(x, w, eps=eps, rows_block=rows_block, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, a, bm, cm, *, chunk=128, interpret=None):
+def ssd_scan(x, dt, a, bm, cm, *, chunk=128, interpret=False):
     """x [B,S,H,P]; dt [B,S,H]; a [H]; bm/cm [B,S,N] (shared across heads)."""
-    interpret = _default_interpret() if interpret is None else interpret
     b, s, h, p = x.shape
     n = bm.shape[-1]
     xf = x.transpose(0, 2, 1, 3).reshape(b * h, s, p)
@@ -64,23 +59,20 @@ def ssd_scan(x, dt, a, bm, cm, *, chunk=128, interpret=None):
 
 
 @partial(jax.jit, static_argnames=("block", "bits", "interpret"))
-def quantize_blocks(x, *, block=1024, bits=8, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+def quantize_blocks(x, *, block=1024, bits=8, interpret=False):
     return _q.quantize_blocks(x, block=block, bits=bits, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("block", "bits", "interpret"))
 def ef_quantize_bucketize(grad, residual, *, block=1024, bits=8,
-                          interpret=None):
+                          interpret=False):
     """Fused EF quantize+bucketize (one pass: t = grad + residual, per-block
     absmax scale, round/clip into the int8 wire buffer, dequantized value,
     new residual)."""
-    interpret = _default_interpret() if interpret is None else interpret
     return _q.ef_quantize_bucketize(grad, residual, block=block, bits=bits,
                                     interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
-def dequant_add(q, scales, acc, *, block=1024, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+def dequant_add(q, scales, acc, *, block=1024, interpret=False):
     return _q.dequant_add(q, scales, acc, block=block, interpret=interpret)

@@ -45,7 +45,8 @@ from repro.parallel.sharding import (
     cache_partition_specs,
     param_partition_specs,
 )
-from repro.train.train_step import abstract_train_state, make_train_step
+from repro.train.train_step import (
+    abstract_train_state, make_train_step, train_state_specs)
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
@@ -90,20 +91,12 @@ def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, tc: TrainConfig):
     """Returns (lowered, compiled).  Pure ShapeDtypeStruct inputs."""
     pctx.set_mesh(mesh)
     ba = _batch_axes(mesh, shape.global_batch)
-    # ZeRO-3 shards params/optimizer over every DP axis (data AND pod)
+    # serving shards params ZeRO-3 style over every DP axis (data AND pod)
     dp_all = tuple(a for a in ("data", "pod") if a in mesh.axis_names)
-    fsdp_axis = dp_all if tc.fsdp else None
 
     if shape.kind == "train":
         state = abstract_train_state(cfg, tc)
-        pspecs = param_partition_specs(state["params"], fsdp_axis)
-        state_specs = {
-            "params": pspecs,
-            "opt": {"m": pspecs, "v": pspecs, "count": P()},
-            "step": P(),
-        }
-        if "ef" in state:
-            state_specs["ef"] = pspecs
+        state_specs = train_state_specs(state, mesh, tc.fsdp)
         batch = mapi.train_batch_specs(cfg, shape)
         bspecs = batch_partition_specs(batch, ba)
         step = make_train_step(cfg, tc, mesh)

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.checkpoint import load_latest
 from repro.configs import registry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api as mapi
 from repro.serve import Engine
 
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = registry.get(args.arch, smoke=args.smoke)
     api = mapi.get_api(cfg, remat="none")
     params = api.init(jax.random.key(args.seed))

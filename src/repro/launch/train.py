@@ -3,10 +3,15 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --smoke \
         --steps 100 --sync wrht --data corpus
 
-On this CPU container use --smoke (reduced config, host device count 1).  On
-real hardware drop --smoke and optionally --multi-pod; everything else is
-identical — mesh construction, sharding, WRHT sync, checkpointing and the
-fault-tolerance runtime are the same code path.
+--smoke selects the reduced same-family config, for a CPU run
+(``JAX_PLATFORMS=cpu``).  On a TPU drop --smoke; everything else is identical
+— mesh construction, sharding, WRHT sync, checkpointing and the
+fault-tolerance runtime are the same code path.  ``python chip_smoke.py``
+at the repo root drives this path once on one chip (``--chips 4``: the
+data-parallel sync modes on a v5e:2x2).
+
+A run resumes from the newest checkpoint in --ckpt-dir; point it at a fresh
+directory to train from step 0.
 """
 
 from __future__ import annotations
@@ -19,6 +24,8 @@ import jax
 from repro.configs import registry
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import CorpusLM, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.parallel import context as pctx
 from repro.runtime.fault_tolerance import FailureInjector
 from repro.train import Trainer, TrainerOptions
@@ -39,7 +46,8 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="checkpoints")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--mesh", default=None,
-                    help="e.g. 2x2x2 (axes pod,data,model); default: no mesh")
+                    help="4 (axis data), 2x2 (data,model) or 2x2x2 "
+                         "(pod,data,model); default: no mesh")
     ap.add_argument("--fail-at", type=int, nargs="*", default=(),
                     help="inject failures at these steps (recovery demo)")
     ap.add_argument("--remat", default="none")
@@ -47,6 +55,7 @@ def main() -> None:
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    enable_compile_cache()
     cfg = registry.get(args.arch, smoke=args.smoke)
     tc = TrainConfig(lr=args.lr, total_steps=args.steps, warmup_steps=min(20, args.steps // 5 + 1),
                      remat=args.remat, sync_algorithm=args.sync, sync_m=args.sync_m,
@@ -54,10 +63,7 @@ def main() -> None:
 
     mesh = None
     if args.mesh:
-        dims = tuple(int(x) for x in args.mesh.split("x"))
-        axes = ("pod", "data", "model")[-len(dims):] if len(dims) < 3 else ("pod", "data", "model")
-        from jax.sharding import AxisType
-        mesh = jax.make_mesh(dims, axes, axis_types=(AxisType.Auto,) * len(dims))
+        mesh = make_mesh(tuple(int(x) for x in args.mesh.split("x")))
         pctx.set_mesh(mesh)
 
     src_cls = CorpusLM if args.data == "corpus" else SyntheticLM

@@ -562,7 +562,7 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.A
 
 def init_embed(key, cfg: ModelConfig, dtype) -> dict:
     ks = jax.random.split(key, 3)
-    p = {"tok": _dense_init(ks[0], (cfg.padded_vocab, cfg.d_model), dtype, scale=1.0)}
+    p = {"tok": _dense_init(ks[0], (cfg.padded_vocab, cfg.d_model), dtype)}
     if not cfg.tie_embeddings:
         p["unembed"] = _dense_init(ks[1], (cfg.d_model, cfg.padded_vocab), dtype)
     if cfg.pos_embed == "learned":

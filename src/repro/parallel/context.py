@@ -1,6 +1,7 @@
 """Process-global mesh context + activation sharding constraints.
 
-Launch code installs the mesh once (``set_mesh``); model code calls
+Launch code installs the mesh once (``set_mesh``, inside ``jax.set_mesh``
+for the same mesh); model code calls
 ``constrain(x, *axes)`` freely — it is a no-op when no mesh is installed
 (CPU smoke tests) or when a named axis is absent from the installed mesh
 (e.g. 'pod' on the single-pod mesh).
@@ -75,11 +76,7 @@ def model_axis_size() -> int:
 def _manual_axes() -> frozenset[str]:
     """Mesh axes currently under manual shard_map control (must be omitted
     from sharding constraints issued by model code running inside)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        return frozenset(am.manual_axes)
-    except Exception:
-        return frozenset()
+    return frozenset(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def constrain(x, *axes):
@@ -97,12 +94,8 @@ def constrain(x, *axes):
         if r is not None:
             r = tuple(n for n in r if n not in manual) or None
         resolved.append(r)
-    spec = P(*resolved)
-    try:
-        # bare PartitionSpec resolves against the context (abstract) mesh —
-        # required inside shard_map, where axis types are Manual and a
-        # NamedSharding over the Auto-typed concrete mesh would mismatch
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.NamedSharding(_MESH, spec))
+    # bare PartitionSpec resolves against the context mesh (``jax.set_mesh``,
+    # which launch code enters around tracing) — required inside shard_map,
+    # where axis types are Manual and a NamedSharding over the Auto-typed
+    # concrete mesh would mismatch
+    return jax.lax.with_sharding_constraint(x, P(*resolved))

@@ -73,6 +73,7 @@ from repro.core import bucketing, compression, planner
 from repro.core import collectives as C
 from repro.models import api as mapi
 from repro.optim import adamw_init, adamw_update, make_lr_schedule
+from repro.parallel.sharding import param_partition_specs
 
 MANUAL_ALGOS = ("psum", "ring", "rd", "bt", "wrht", "hier_faithful",
                 "hier_scatter", "planned", "planned_sharded",
@@ -117,6 +118,33 @@ def make_train_state(cfg: ModelConfig, tc: TrainConfig, key) -> dict:
 def abstract_train_state(cfg: ModelConfig, tc: TrainConfig):
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
     return jax.eval_shape(lambda k: make_train_state(cfg, tc, k), key)
+
+
+def _on_mesh(spec: P, axis_names) -> P:
+    """``spec`` with every mesh axis that ``axis_names`` lacks dropped."""
+    def keep(entry):
+        if isinstance(entry, tuple):
+            return tuple(a for a in entry if a in axis_names) or None
+        return entry if entry in axis_names else None
+
+    return P(*(keep(e) for e in spec))
+
+
+def train_state_specs(state, mesh, fsdp: bool = False) -> dict:
+    """PartitionSpecs of the train state on ``mesh``: params, optimizer
+    moments and EF residual by the partition rules (TP over 'model', ZeRO
+    over every DP axis when ``fsdp``), counters replicated.  Axes the mesh
+    lacks are dropped, so on a DP-only mesh the state is replicated."""
+    pspecs = param_partition_specs(state["params"],
+                                   dp_axes_of(mesh) if fsdp else None)
+    pspecs = jax.tree.map(lambda s: _on_mesh(s, mesh.axis_names), pspecs,
+                          is_leaf=lambda x: isinstance(x, P))
+    specs = {"params": pspecs,
+             "opt": {"m": pspecs, "v": pspecs, "count": P()},
+             "step": P()}
+    if "ef" in state:
+        specs["ef"] = pspecs
+    return specs
 
 
 # ---------------------------------------------------------------------------
@@ -737,15 +765,9 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, mesh=None):
     dp = dp_axes_of(mesh)
 
     def _shard_map(fn, in_specs, out_specs):
-        try:
-            sm = jax.shard_map
-        except AttributeError:  # pre-jax.shard_map fallback
-            from jax.experimental.shard_map import shard_map as sm_old
-
-            return sm_old(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=set(dp), check_vma=False)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, axis_names=set(dp),
+                             check_vma=False)
 
     # state replicated over DP axes, sharded over 'model' per param rules is
     # delegated to GSPMD ('model' stays an auto axis inside shard_map).

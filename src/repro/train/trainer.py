@@ -10,18 +10,21 @@ run — asserted by tests/test_fault_tolerance.py.
 from __future__ import annotations
 
 import logging
-import time
 from dataclasses import dataclass, field
+from functools import partial
 from pathlib import Path
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import Checkpointer
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.data.pipeline import shard_batch
 from repro.runtime.fault_tolerance import (
     FailureInjector, FaultManager, InjectedFailure, StepWatchdog)
-from .train_step import make_train_state, make_train_step
+from .train_step import (
+    abstract_train_state, make_train_state, make_train_step,
+    train_state_specs)
 
 log = logging.getLogger("repro.trainer")
 
@@ -66,7 +69,22 @@ class Trainer:
         # the online re-plan controller (planned_sharded only): kept off the
         # jitted callable, which jax.jit would strip (DESIGN.md §12)
         self.controller = getattr(raw_step, "controller", None)
-        self._step_fn = jax.jit(raw_step)
+        # with a mesh the state's layout is pinned (train_state_specs): the
+        # step returns it as it came in, so the next step hits the same
+        # executable instead of one GSPMD re-laid out
+        self._state_shardings = None
+        if self.mesh is not None:
+            specs = train_state_specs(abstract_train_state(self.cfg, self.tc),
+                                      self.mesh, self.tc.fsdp)
+            self._state_shardings = jax.tree.map(
+                lambda s: NamedSharding(self.mesh, s), specs,
+                is_leaf=lambda x: isinstance(x, P))
+        # the state is donated: the old params and optimizer state are dead
+        # once the step returns (Checkpointer.save copies to the host before
+        # the next step), so the step updates them in place instead of
+        # holding two copies on the device
+        self._step_fn = jax.jit(raw_step, donate_argnums=0,
+                                out_shardings=(self._state_shardings, None))
         self._plan_codes = (None if self.controller is None
                             else self.controller.arrays())
         self._ckpt_requested = False
@@ -109,12 +127,21 @@ class Trainer:
 
     # -------------------------------------------------------------- state
     def init_or_restore(self):
-        state = make_train_state(self.cfg, self.tc, jax.random.key(self.tc.seed))
+        """The newest checkpoint in ``ckpt_dir``, else a fresh state from
+        ``tc.seed``.  With a mesh the state is laid out over all of its
+        devices as the step returns it, not left on the default one."""
         steps = self.ckpt.steps()
         if steps:
-            state = self.ckpt.restore(steps[-1], state)
+            target = abstract_train_state(self.cfg, self.tc)
+            specs = (None if self.mesh is None else jax.tree.map(
+                lambda s: s.spec, self._state_shardings))
+            state = self.ckpt.restore(steps[-1], target, mesh=self.mesh,
+                                      spec_tree=specs)
             log.info("restored checkpoint at step %d", steps[-1])
-        return state
+            return state
+        init = jax.jit(partial(make_train_state, self.cfg, self.tc),
+                       out_shardings=self._state_shardings)
+        return init(jax.random.key(self.tc.seed))
 
     # ---------------------------------------------------------------- run
     def run(self, total_steps: int | None = None):

@@ -9,18 +9,6 @@ import pytest
 REPO = Path(__file__).resolve().parents[1]
 SRC = REPO / "src"
 
-# Version gate for the multi-device subprocess tests: probe the installed
-# jax ONCE for the features they need instead of pattern-matching subprocess
-# stderr.  On a jax that actually lacks jax.sharding.AxisType the tests
-# skip with a precise reason; on any newer jax they execute — and an
-# AxisType import error there is a real failure, never a silent skip.
-try:
-    from jax.sharding import AxisType as _AxisType  # noqa: F401
-
-    HAVE_AXISTYPE = True
-except ImportError:
-    HAVE_AXISTYPE = False
-
 
 def run_subprocess(code: str, devices: int = 8, timeout: int = 600) -> str:
     """Run python code in a fresh process with N fake XLA host devices.
@@ -36,17 +24,6 @@ def run_subprocess(code: str, devices: int = 8, timeout: int = 600) -> str:
         capture_output=True, text=True, timeout=timeout, env=env,
     )
     if proc.returncode != 0:
-        missing_axistype = "cannot import name 'AxisType'" in proc.stderr
-        if missing_axistype and not HAVE_AXISTYPE:
-            # Genuine environment limitation (verified against the installed
-            # jax above), not a repo regression: skip instead of carrying
-            # known-red tests.  CI images with a current jax never take this
-            # branch — there the tests run and must pass.
-            pytest.skip(
-                "jax.sharding.AxisType absent from the installed jax "
-                "(feature-probed at collection); multi-device subprocess "
-                "tests cannot run in this environment"
-            )
         raise AssertionError(
             f"subprocess failed (rc={proc.returncode})\n--- stdout\n"
             f"{proc.stdout}\n--- stderr\n{proc.stderr}"

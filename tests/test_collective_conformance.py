@@ -523,65 +523,59 @@ else:  # pragma: no cover - exercised only without hypothesis installed
 # ---------------------------------------------------------------------------
 # layer 3: device-level shard_map twins on 8 simulated devices
 # ---------------------------------------------------------------------------
-# The subprocess uses a shard_map compat shim (jax.shard_map, else the
-# experimental API) so the twins run even on jax builds that predate
-# jax.shard_map — unlike the AxisType-gated mesh tests, nothing here needs
-# a named-axis-typed mesh.
 
 TWINS = """
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import collectives as C
 
-try:
-    _sm = jax.shard_map
-    def smap(body):
-        return _sm(body, mesh=mesh, in_specs=P('ax'), out_specs=P('ax'),
-                   axis_names={'ax'})
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _sm
-    def smap(body):
-        return _sm(body, mesh=mesh, in_specs=P('ax'), out_specs=P('ax'),
-                   check_rep=False)
+def smap(body):
+    return jax.shard_map(body, mesh=mesh, in_specs=P('ax'), out_specs=P('ax'),
+                         axis_names={'ax'})
 
 S = 8
 mesh = Mesh(np.array(jax.devices()).reshape(S,), ('ax',))
 rng = np.random.default_rng(0)
-x = jnp.asarray(rng.normal(size=(S, 131)).astype(np.float32))  # odd: pad paths
-xs = np.asarray(x)
-total = xs.sum(0)
-pad = (-131) % S
-padded = np.concatenate([total, np.zeros(pad, np.float32)])
-shards = padded.reshape(S, -1)
 
 def run(body):
     return np.asarray(jax.jit(smap(body))(x))
 
-# reduce-scatter twins: device i ends owning fully-reduced chunk i — the
-# exact ownership map of the scheduled reduce_scatter collective
-for name, fn in (('ring', C.reduce_scatter_ring),
-                 ('alltoall', C.reduce_scatter_alltoall)):
-    got = run(lambda st, fn=fn: fn(st[0], 'ax', S)[None])
-    assert np.abs(got - shards).max() < 1e-4, ('rs', name)
+# 131: odd, the pad paths; 3072: chunks of 3 x 128, the lane-layout view
+for n in (131, S * 128 * 3):
+    x = jnp.asarray(rng.normal(size=(S, n)).astype(np.float32))
+    xs = np.asarray(x)
+    total = xs.sum(0)
+    pad = (-n) % S
+    padded = np.concatenate([total, np.zeros(pad, np.float32)])
+    shards = padded.reshape(S, -1)
+
+    # reduce-scatter twins: device i ends owning fully-reduced chunk i — the
+    # exact ownership map of the scheduled reduce_scatter collective
+    for name, fn in (('ring', C.reduce_scatter_ring),
+                     ('alltoall', C.reduce_scatter_alltoall)):
+        got = run(lambda st, fn=fn: fn(st[0], 'ax', S)[None])
+        assert np.abs(got - shards).max() < 1e-4, ('rs', name, n)
+
+    # all-gather twins: start from the owned shard, end with the
+    # concatenation
+    for name, fn in (('ring', C.all_gather_ring),
+                     ('alltoall', C.all_gather_alltoall)):
+        def body(st, fn=fn):
+            shard = C.reduce_scatter_ring(st[0], 'ax', S)
+            return fn(shard, 'ax', S)[None]
+        got = run(body)
+        assert np.abs(got - padded[None]).max() < 1e-4, ('ag', name, n)
+
+    # rs+ag composition == psum (the planned_sharded bucket body)
+    def rs_ag(st):
+        flat = st[0]
+        L = flat.shape[0]
+        shard = C.reduce_scatter_ring(flat, 'ax', S)
+        return C.all_gather_ring(shard, 'ax', S)[:L][None]
+    got = run(rs_ag)
+    assert np.abs(got - total[None]).max() < 1e-4, n
 print('RS_TWINS_OK')
-
-# all-gather twins: start from the owned shard, end with the concatenation
-for name, fn in (('ring', C.all_gather_ring), ('alltoall', C.all_gather_alltoall)):
-    def body(st, fn=fn):
-        shard = C.reduce_scatter_ring(st[0], 'ax', S)
-        return fn(shard, 'ax', S)[None]
-    got = run(body)
-    assert np.abs(got - padded[None]).max() < 1e-4, ('ag', name)
 print('AG_TWINS_OK')
-
-# rs+ag composition == psum (the planned_sharded bucket body)
-def rs_ag(st):
-    flat = st[0]
-    L = flat.shape[0]
-    shard = C.reduce_scatter_ring(flat, 'ax', S)
-    return C.all_gather_ring(shard, 'ax', S)[:L][None]
-got = run(rs_ag)
-assert np.abs(got - total[None]).max() < 1e-4
 print('RS_AG_COMPOSE_OK')
 
 # broadcast twin: every device ends with the root's (device 0) value,
@@ -613,16 +607,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.configs.base import TrainConfig
 from repro.train import train_step as TS
 
-try:
-    _sm = jax.shard_map
-    def smap(body, mesh, spec):
-        return _sm(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                   axis_names={'data', 'pod'})
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _sm
-    def smap(body, mesh, spec):
-        return _sm(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                   check_rep=False)
+def smap(body, mesh, spec):
+    return jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
+                         axis_names={'data', 'pod'})
 
 mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ('data', 'pod'))
 tc = TrainConfig(sync_algorithm="planned_sharded", bucket_bytes=1 << 10)

@@ -9,17 +9,19 @@ from repro.core import collectives as C
 
 mesh = jax.make_mesh((8,), ('ax',), axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(0)
-x = jnp.asarray(rng.normal(size=(8, 129)).astype(np.float32))  # odd length: pad paths
-want = np.tile(np.asarray(x).sum(0, keepdims=True), (8, 1))
-with jax.set_mesh(mesh):
-    for alg, kw in [('psum', {}), ('ring', {}), ('rd', {}), ('bt', {}),
-                    ('wrht', {'m': 3}), ('wrht', {'m': 3, 'alltoall_max': 4}),
-                    ('wrht', {'m': 5, 'alltoall_max': 2}), ('wrht', {'m': 8}),
-                    ('wrht', {'m': 2, 'alltoall_max': None})]:
-        f = jax.jit(C.make_sharded_allreduce(mesh, 'ax', alg, **kw))
-        got = np.asarray(f(x))
-        err = np.abs(got - want).max()
-        assert err < 1e-4, (alg, kw, err)
+# 129: odd length, the pad paths; 1024: 128-element chunks, the lane layout
+for n in (129, 1024):
+    x = jnp.asarray(rng.normal(size=(8, n)).astype(np.float32))
+    want = np.tile(np.asarray(x).sum(0, keepdims=True), (8, 1))
+    with jax.set_mesh(mesh):
+        for alg, kw in [('psum', {}), ('ring', {}), ('rd', {}), ('bt', {}),
+                        ('wrht', {'m': 3}), ('wrht', {'m': 3, 'alltoall_max': 4}),
+                        ('wrht', {'m': 5, 'alltoall_max': 2}), ('wrht', {'m': 8}),
+                        ('wrht', {'m': 2, 'alltoall_max': None})]:
+            f = jax.jit(C.make_sharded_allreduce(mesh, 'ax', alg, **kw))
+            got = np.asarray(f(x))
+            err = np.abs(got - want).max()
+            assert err < 1e-4, (alg, kw, n, err)
 print('ALGOS_OK')
 """
 

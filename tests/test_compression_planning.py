@@ -2,11 +2,7 @@
 fused pallas quantize+bucketize kernel against its reference, bits as a
 plan-cache axis, the per-bucket width sweep (including the *decline* on
 latency-bound buckets), the EF-compressed planned sync modes, and the
-8-device equivalence / no-retrace harnesses.
-
-The device-level subprocess tests use the same shard_map compat shim as the
-conformance twins (jax.shard_map, else jax.experimental.shard_map), so they
-run on jax builds that predate jax.shard_map."""
+8-device equivalence / no-retrace harnesses."""
 
 import os
 
@@ -122,7 +118,8 @@ def test_ef_compress_blocks_per_block_scales():
 # fused pallas kernel vs reference (golden equivalence)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("n", [1, 255, 1024, 5000])
+# 300_001 spans several row tiles of the kernel grid, the last one ragged
+@pytest.mark.parametrize("n", [1, 255, 1024, 5000, 300_001])
 @pytest.mark.parametrize("bits", [8, 4])
 def test_fused_kernel_matches_ref(n, bits):
     rng = np.random.default_rng(n * 31 + bits)
@@ -149,7 +146,7 @@ def test_fused_path_matches_jnp_path():
     dj, rj = compression.ef_compress_blocks(flat, resid, bits=8, block=256,
                                             fused=False)
     df, rf = compression.ef_compress_blocks(flat, resid, bits=8, block=256,
-                                            fused=True)
+                                            fused=True, interpret=True)
     np.testing.assert_array_equal(np.asarray(dj), np.asarray(df))
     np.testing.assert_allclose(np.asarray(rj), np.asarray(rf),
                                atol=3e-7, rtol=0)
@@ -158,7 +155,8 @@ def test_fused_path_matches_jnp_path():
 def test_ops_wrapper_jits():
     g = jnp.ones((512,), jnp.float32)
     e = jnp.zeros((512,), jnp.float32)
-    q, s, deq, res, n = kops.ef_quantize_bucketize(g, e, block=256, bits=8)
+    q, s, deq, res, n = kops.ef_quantize_bucketize(
+        g, e, block=256, bits=8, interpret=True)
     assert n == 512 and q.dtype == jnp.int8 and s.shape == (2,)
     np.testing.assert_allclose(np.asarray(deq), 1.0, atol=1e-2)
 
@@ -470,7 +468,7 @@ def test_ef_convergence_50_steps():
 
 
 # ---------------------------------------------------------------------------
-# device-level harnesses (8 simulated devices, compat shim)
+# device-level harnesses (8 simulated devices)
 # ---------------------------------------------------------------------------
 
 PLANNED_COMPRESSED_EQ = """
@@ -479,16 +477,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.configs.base import TrainConfig
 from repro.train import train_step as TS
 
-try:
-    _sm = jax.shard_map
-    def smap(body, mesh, in_specs, out_specs):
-        return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   axis_names={'data', 'pod'})
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _sm
-    def smap(body, mesh, in_specs, out_specs):
-        return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+def smap(body, mesh, in_specs, out_specs):
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={'data', 'pod'})
 
 mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ('data', 'pod'))
 rng = np.random.default_rng(0)
@@ -552,16 +543,9 @@ from repro.configs.base import TrainConfig
 from repro.core.topology import FailureMask
 from repro.train import train_step as TS
 
-try:
-    _sm = jax.shard_map
-    def smap(body, mesh, in_specs, out_specs):
-        return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   axis_names={'data', 'pod'})
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _sm
-    def smap(body, mesh, in_specs, out_specs):
-        return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+def smap(body, mesh, in_specs, out_specs):
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={'data', 'pod'})
 
 mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ('data', 'pod'))
 tc = TrainConfig(sync_algorithm='planned_sharded_compressed',

@@ -326,9 +326,8 @@ def test_sync_controller_infeasible_keeps_previous_plan():
 # ---------------------------------------------------------------------------
 # device-level E2E: mid-run plan swap with NO retrace (8 simulated devices)
 # ---------------------------------------------------------------------------
-# Uses the same shard_map compat shim as the conformance twins, so this runs
-# on jax builds that predate jax.shard_map too.  The jitted body counts its
-# own traces; swapping healthy -> degraded codes must not add one.
+# The jitted body counts its own traces; swapping healthy -> degraded codes
+# must not add one.
 
 NO_RETRACE = """
 import jax, numpy as np, jax.numpy as jnp
@@ -337,16 +336,9 @@ from repro.configs.base import TrainConfig
 from repro.core.topology import FailureMask
 from repro.train import train_step as TS
 
-try:
-    _sm = jax.shard_map
-    def smap(body, mesh, in_specs, out_specs):
-        return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   axis_names={'data', 'pod'})
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _sm
-    def smap(body, mesh, in_specs, out_specs):
-        return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+def smap(body, mesh, in_specs, out_specs):
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={'data', 'pod'})
 
 mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ('data', 'pod'))
 tc = TrainConfig(sync_algorithm="planned_sharded", bucket_bytes=1 << 10)
@@ -408,7 +400,7 @@ def test_midrun_plan_swap_no_retrace(subproc):
 
 # trainer-level E2E on a typed mesh: the injector reports a mask mid-run and
 # the trainer re-plans through the controller with no retrace of the jitted
-# step.  Needs jax.shard_map + AxisType (conftest skips on older jax).
+# step.
 TRAINER_REPLAN = """
 import jax, numpy as np
 from jax.sharding import AxisType
@@ -430,7 +422,7 @@ with jax.set_mesh(mesh):
                      sync_algorithm="planned_sharded", bucket_bytes=1 << 20)
     src = SyntheticLM(cfg.vocab_size, 16, 8)
     tr = Trainer(cfg, tc, src, mesh=mesh,
-                 options=TrainerOptions(ckpt_dir="ckpt_replan", ckpt_every=100,
+                 options=TrainerOptions(ckpt_dir=CKPT_DIR, ckpt_every=100,
                                         log_every=100),
                  injector=FailureInjector(degrade_at={3: mask}))
     assert tr.controller is not None
@@ -446,8 +438,9 @@ print("TRAINER_REPLAN_OK", tr.controller.replan_count)
 """
 
 
-def test_trainer_replans_midrun_multidevice(subproc):
-    assert "TRAINER_REPLAN_OK" in subproc(TRAINER_REPLAN, timeout=900)
+def test_trainer_replans_midrun_multidevice(subproc, tmp_path):
+    code = f"CKPT_DIR = {str(tmp_path)!r}\n" + TRAINER_REPLAN
+    assert "TRAINER_REPLAN_OK" in subproc(code, timeout=900)
 
 
 # trainer-level E2E of the CLOSED loop (DESIGN.md §14): no injected mask —
@@ -479,7 +472,7 @@ with jax.set_mesh(mesh):
                      sync_algorithm="planned_sharded", bucket_bytes=1 << 20)
     src = SyntheticLM(cfg.vocab_size, 16, 8)
     tr = Trainer(cfg, tc, src, mesh=mesh,
-                 options=TrainerOptions(ckpt_dir="ckpt_loop", ckpt_every=100,
+                 options=TrainerOptions(ckpt_dir=CKPT_DIR, ckpt_every=100,
                                         log_every=100),
                  fault_manager=mgr)
     assert tr.controller is not None
@@ -495,5 +488,6 @@ print("FAULT_LOOP_OK", mgr.replan_count)
 """
 
 
-def test_trainer_closed_fault_loop_multidevice(subproc):
-    assert "FAULT_LOOP_OK" in subproc(TRAINER_FAULT_LOOP, timeout=900)
+def test_trainer_closed_fault_loop_multidevice(subproc, tmp_path):
+    code = f"CKPT_DIR = {str(tmp_path)!r}\n" + TRAINER_FAULT_LOOP
+    assert "FAULT_LOOP_OK" in subproc(code, timeout=900)

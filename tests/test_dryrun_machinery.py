@@ -11,10 +11,10 @@ import jax
 from repro.configs import registry
 from repro.configs.base import ShapeConfig
 from repro.launch.dryrun import lower_cell, _memory, _costs, _train_config
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch import analytic
 
-mesh = make_host_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2))
 cfg = registry.get("qwen2-1.5b", smoke=True)
 shapes = [ShapeConfig("t", 64, 8, "train"), ShapeConfig("p", 64, 8, "prefill"),
           ShapeConfig("d", 64, 8, "decode")]
