@@ -476,6 +476,9 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.A
     local per data shard; the [G, E, C, *] buffers are 2-D sharded
     (data × model).  A globally-sorted variant was measured 20+ GiB/device
     worse (see EXPERIMENTS.md §Perf, hypothesis log).
+
+    The expert FFNs are named scope ``ffn``; routing, dispatch, combine and
+    the aux loss are ``moe_dispatch``.
     """
     from repro.parallel import context as pctx
 
@@ -485,74 +488,78 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.A
     g = _moe_groups(t)
     tg = t // g
     e, k = mo.n_experts, mo.top_k
-    xt = pctx.constrain(x.reshape(g, tg, d), pctx.BATCH, None, None)
+    with jax.named_scope("moe_dispatch"):
+        xt = pctx.constrain(x.reshape(g, tg, d), pctx.BATCH, None, None)
 
-    logits = (xt @ p["router"].astype(x.dtype)).astype(jnp.float32)   # [G,Tg,E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, eids = lax.top_k(probs, k)                               # [G,Tg,k]
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+        logits = (xt @ p["router"].astype(x.dtype)).astype(jnp.float32)   # [G,Tg,E]
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, eids = lax.top_k(probs, k)                               # [G,Tg,k]
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
 
-    # aux load-balancing loss (Switch-style, computed over all tokens)
-    gi = jnp.arange(g)[:, None]
-    density = jnp.zeros((g, e), jnp.float32).at[
-        jnp.broadcast_to(gi[..., None], eids.shape), eids].add(1.0)
-    density = density.sum(0) / (t * k)
-    router_prob = probs.mean((0, 1))
-    aux = e * jnp.sum(density * router_prob) * mo.router_aux_weight
+        # aux load-balancing loss (Switch-style, computed over all tokens)
+        gi = jnp.arange(g)[:, None]
+        density = jnp.zeros((g, e), jnp.float32).at[
+            jnp.broadcast_to(gi[..., None], eids.shape), eids].add(1.0)
+        density = density.sum(0) / (t * k)
+        router_prob = probs.mean((0, 1))
+        aux = e * jnp.sum(density * router_prob) * mo.router_aux_weight
 
-    cap = int(mo.capacity_factor * k * tg / e) + 1                    # C per (group, expert)
-    tgk = tg * k
+        cap = int(mo.capacity_factor * k * tg / e) + 1                    # C per (group, expert)
+        tgk = tg * k
 
-    # ---- gather-only dispatch.  The obvious scatter formulation
-    # (slot_buf.at[g, e, c].set(tokens)) makes GSPMD's scatter partitioner
-    # replicate both operands with full-size all-reduces (+95 GiB/device on
-    # the 236B cell, see the §Perf hypothesis log); with the sort, every
-    # expert's entries are a contiguous range, so slots can be *gathered*.
-    flat_e = eids.reshape(g, tgk)                                     # [G,Tg*k]
-    order = jnp.argsort(flat_e, axis=-1)
-    sorted_e = jnp.take_along_axis(flat_e, order, axis=-1)
-    inv_order = jnp.argsort(order, axis=-1)                           # entry -> sorted pos
-    counts = jnp.zeros((g, e), jnp.int32).at[
-        jnp.broadcast_to(gi, flat_e.shape), flat_e].add(1)            # tiny scatter
-    seg_start = jnp.cumsum(counts, axis=-1) - counts                  # [G,E]
+        # ---- gather-only dispatch.  The obvious scatter formulation
+        # (slot_buf.at[g, e, c].set(tokens)) makes GSPMD's scatter partitioner
+        # replicate both operands with full-size all-reduces (+95 GiB/device on
+        # the 236B cell, see the §Perf hypothesis log); with the sort, every
+        # expert's entries are a contiguous range, so slots can be *gathered*.
+        flat_e = eids.reshape(g, tgk)                                     # [G,Tg*k]
+        order = jnp.argsort(flat_e, axis=-1)
+        sorted_e = jnp.take_along_axis(flat_e, order, axis=-1)
+        inv_order = jnp.argsort(order, axis=-1)                           # entry -> sorted pos
+        counts = jnp.zeros((g, e), jnp.int32).at[
+            jnp.broadcast_to(gi, flat_e.shape), flat_e].add(1)            # tiny scatter
+        seg_start = jnp.cumsum(counts, axis=-1) - counts                  # [G,E]
 
-    # slot (e, c) reads sorted position seg_start[e] + c while c < counts[e]
-    slot_src = seg_start[..., None] + jnp.arange(cap)[None, None]     # [G,E,C]
-    slot_valid = jnp.arange(cap)[None, None] < counts[..., None]
-    slot_src = jnp.clip(slot_src, 0, tgk - 1).reshape(g, e * cap)
-    tok_of = order // k                                               # [G,Tg*k]
-    slot_tok = jnp.take_along_axis(tok_of, slot_src, axis=1)          # [G,E*C]
-    xs = jnp.take_along_axis(xt, slot_tok[..., None], axis=1)         # [G,E*C,d]
-    slot_buf = jnp.where(slot_valid.reshape(g, e * cap, 1), xs, 0)
-    slot_buf = slot_buf.reshape(g, e, cap, d)
-    slot_buf = pctx.constrain(slot_buf, pctx.BATCH, pctx.MODEL, None, None)
+        # slot (e, c) reads sorted position seg_start[e] + c while c < counts[e]
+        slot_src = seg_start[..., None] + jnp.arange(cap)[None, None]     # [G,E,C]
+        slot_valid = jnp.arange(cap)[None, None] < counts[..., None]
+        slot_src = jnp.clip(slot_src, 0, tgk - 1).reshape(g, e * cap)
+        tok_of = order // k                                               # [G,Tg*k]
+        slot_tok = jnp.take_along_axis(tok_of, slot_src, axis=1)          # [G,E*C]
+        xs = jnp.take_along_axis(xt, slot_tok[..., None], axis=1)         # [G,E*C,d]
+        slot_buf = jnp.where(slot_valid.reshape(g, e * cap, 1), xs, 0)
+        slot_buf = slot_buf.reshape(g, e, cap, d)
+        slot_buf = pctx.constrain(slot_buf, pctx.BATCH, pctx.MODEL, None, None)
 
-    # expert FFN: [G,E,C,d] x [E,d,f] -> [G,E,C,f]; d contracted, E sharded
-    h_g = jnp.einsum("gecd,edf->gecf", slot_buf, p["w_gate"].astype(x.dtype))
-    h_u = jnp.einsum("gecd,edf->gecf", slot_buf, p["w_up"].astype(x.dtype))
-    h = jax.nn.silu(h_g) * h_u
-    y_e = jnp.einsum("gecf,efd->gecd", h, p["w_down"].astype(x.dtype))
-    # replicate the (small) expert outputs over 'model' for the local
-    # combine-gather — this reshard is the EP "return" all-to-all
-    y_e = pctx.constrain(y_e, pctx.BATCH, None, None, None)
+    with jax.named_scope("ffn"):
+        # expert FFN: [G,E,C,d] x [E,d,f] -> [G,E,C,f]; d contracted, E sharded
+        h_g = jnp.einsum("gecd,edf->gecf", slot_buf, p["w_gate"].astype(x.dtype))
+        h_u = jnp.einsum("gecd,edf->gecf", slot_buf, p["w_up"].astype(x.dtype))
+        h = jax.nn.silu(h_g) * h_u
+        y_e = jnp.einsum("gecf,efd->gecd", h, p["w_down"].astype(x.dtype))
+    with jax.named_scope("moe_dispatch"):
+        # replicate the (small) expert outputs over 'model' for the local
+        # combine-gather — this reshard is the EP "return" all-to-all
+        y_e = pctx.constrain(y_e, pctx.BATCH, None, None, None)
 
-    # combine: entry j (sorted) lives at flat slot sorted_e*C + pos; dropped
-    # entries (pos >= C) are masked.  Un-sort via the inverse permutation and
-    # fold k back into the token dim with a reshape+sum — no scatter.
-    pos_in_e = jnp.arange(tgk)[None] - jnp.take_along_axis(
-        seg_start, sorted_e, axis=-1)                                 # [G,Tg*k]
-    dropped = pos_in_e >= cap
-    slot_of = sorted_e * cap + jnp.clip(pos_in_e, 0, cap - 1)
-    y_sorted = jnp.take_along_axis(
-        y_e.reshape(g, e * cap, d), slot_of[..., None], axis=1)
-    y_sorted = jnp.where(dropped[..., None], 0, y_sorted)
-    y_entries = jnp.take_along_axis(y_sorted, inv_order[..., None], axis=1)
-    contrib = y_entries * weights.reshape(g, tgk)[..., None].astype(x.dtype)
-    out = contrib.reshape(g, tg, k, d).sum(axis=2)                    # [G,Tg,d]
-    out = pctx.constrain(out, pctx.BATCH, None, None)
+        # combine: entry j (sorted) lives at flat slot sorted_e*C + pos; dropped
+        # entries (pos >= C) are masked.  Un-sort via the inverse permutation and
+        # fold k back into the token dim with a reshape+sum — no scatter.
+        pos_in_e = jnp.arange(tgk)[None] - jnp.take_along_axis(
+            seg_start, sorted_e, axis=-1)                                 # [G,Tg*k]
+        dropped = pos_in_e >= cap
+        slot_of = sorted_e * cap + jnp.clip(pos_in_e, 0, cap - 1)
+        y_sorted = jnp.take_along_axis(
+            y_e.reshape(g, e * cap, d), slot_of[..., None], axis=1)
+        y_sorted = jnp.where(dropped[..., None], 0, y_sorted)
+        y_entries = jnp.take_along_axis(y_sorted, inv_order[..., None], axis=1)
+        contrib = y_entries * weights.reshape(g, tgk)[..., None].astype(x.dtype)
+        out = contrib.reshape(g, tg, k, d).sum(axis=2)                    # [G,Tg,d]
+        out = pctx.constrain(out, pctx.BATCH, None, None)
 
     if mo.n_shared:
-        out = out + mlp_apply(p["shared"], xt, cfg)
+        with jax.named_scope("ffn"):
+            out = out + mlp_apply(p["shared"], xt, cfg)
     return out.reshape(b, s, d), aux
 
 

@@ -77,20 +77,23 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
 def _layer_fwd(p, x, cfg, positions, *, cache=None, cache_index=None,
                window=None, dense=False):
     h = L.norm_apply(p["ln1"], x, cfg)
-    if _use_mla(cfg):
-        a, new_cache = L.mla_apply(p["attn"], h, cfg, positions,
-                                   cache=cache, cache_index=cache_index)
-    else:
-        a, new_cache = L.attention_apply(p["attn"], h, cfg, positions,
-                                         causal=True, window=window,
-                                         cache=cache, cache_index=cache_index)
+    with jax.named_scope("attention"):
+        if _use_mla(cfg):
+            a, new_cache = L.mla_apply(p["attn"], h, cfg, positions,
+                                       cache=cache, cache_index=cache_index)
+        else:
+            a, new_cache = L.attention_apply(p["attn"], h, cfg, positions,
+                                             causal=True, window=window,
+                                             cache=cache, cache_index=cache_index)
     x = x + a
     h = L.norm_apply(p["ln2"], x, cfg)
     aux = jnp.zeros((), jnp.float32)
     if _use_moe(cfg, dense):
+        # scopes its expert FFNs as "ffn" and the rest as "moe_dispatch"
         m, aux = L.moe_apply(p["moe"], h, cfg)
     else:
-        m = L.mlp_apply(p["mlp"], h, cfg)
+        with jax.named_scope("ffn"):
+            m = L.mlp_apply(p["mlp"], h, cfg)
     x = x + m
     x = pctx.constrain_acts(x)
     return x, new_cache, aux
@@ -111,7 +114,8 @@ def forward(
     """Returns (hidden [B,S,d], new_cache | None, aux_loss)."""
     b, s = tokens.shape
     base_pos = 0 if cache_index is None else cache_index
-    x = L.embed_apply(params["embed"], tokens, cfg, compute_dtype)
+    with jax.named_scope("embed_head"):
+        x = L.embed_apply(params["embed"], tokens, cfg, compute_dtype)
 
     if patch_embeds is not None:
         pe = patch_embeds.astype(compute_dtype) @ params["patch_proj"].astype(compute_dtype)
@@ -129,12 +133,14 @@ def forward(
 
     # unstacked dense-FFN layers first (deepseek-v2 first_k_dense)
     dense_caches = []
-    for i, dp in enumerate(params.get("dense_layers", [])):
-        dcache = None if cache is None else jax.tree.map(lambda c: c[i], cache["dense"])
-        x, ncache, aux = _layer_fwd(dp, x, cfg, positions, cache=dcache,
-                                    cache_index=cache_index, window=window, dense=True)
-        dense_caches.append(ncache)
-        aux_total = aux_total + aux
+    with jax.named_scope("layers"):
+        for i, dp in enumerate(params.get("dense_layers", [])):
+            dcache = None if cache is None else jax.tree.map(lambda c: c[i], cache["dense"])
+            x, ncache, aux = _layer_fwd(dp, x, cfg, positions, cache=dcache,
+                                        cache_index=cache_index, window=window,
+                                        dense=True)
+            dense_caches.append(ncache)
+            aux_total = aux_total + aux
 
     def body(carry, layer_in):
         xc, auxc = carry
@@ -150,8 +156,9 @@ def forward(
             body, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
 
     scan_cache = None if cache is None else cache["scan"]
-    (x, aux_total), new_scan_cache = lax.scan(
-        body, (x, aux_total), (params["layers"], scan_cache))
+    with jax.named_scope("layers"):
+        (x, aux_total), new_scan_cache = lax.scan(
+            body, (x, aux_total), (params["layers"], scan_cache))
 
     new_cache = None
     if cache is not None:
@@ -159,7 +166,8 @@ def forward(
         if dense_caches:
             new_cache["dense"] = jax.tree.map(
                 lambda *xs: jnp.stack(xs), *dense_caches)
-    x = L.norm_apply(params["final_norm"], x, cfg)
+    with jax.named_scope("embed_head"):
+        x = L.norm_apply(params["final_norm"], x, cfg)
     return x, new_cache, aux_total
 
 
@@ -183,8 +191,9 @@ def loss_fn(params, batch: dict, cfg: ModelConfig, *, compute_dtype=jnp.bfloat16
     labels = batch["labels"]
     if batch.get("patch_embeds") is not None:
         hidden = hidden[:, -labels.shape[1]:]  # loss over text positions only
-    logits = logits_fn(params, hidden, cfg)
-    loss = L.masked_xent(logits, labels)
+    with jax.named_scope("embed_head"):
+        logits = logits_fn(params, hidden, cfg)
+        loss = L.masked_xent(logits, labels)
     return loss + aux, {"nll": loss, "aux": aux}
 
 

@@ -97,6 +97,9 @@ class StragglerEvent:
     step: int
     duration_s: float
     median_s: float
+    # seconds of the step spent compiling: a flagged step whose compile
+    # explains its time recompiled, and was not slow
+    compile_s: float = 0.0
 
 
 class StepWatchdog:
@@ -125,14 +128,16 @@ class StepWatchdog:
     def start(self) -> None:
         self._t0 = self.clock()
 
-    def stop(self, step: int) -> float:
+    def stop(self, step: int, compile_s: float = 0.0) -> float:
+        """The step's seconds since ``start``; ``compile_s`` of them were
+        compilation, and ride on the step's event if it is flagged."""
         assert self._t0 is not None, "stop() without start()"
         dt = self.clock() - self._t0
         self._t0 = None
         if len(self._times) >= self.warmup:
             med = statistics.median(self._times)
             if dt > self.threshold * med:
-                ev = StragglerEvent(step, dt, med)
+                ev = StragglerEvent(step, dt, med, compile_s)
                 self.events.append(ev)
                 if self.on_straggler is not None:
                     self.on_straggler(ev)

@@ -747,12 +747,15 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, mesh=None):
             accum_dtype=_dtype(tc.grad_accum_dtype))
         new_ef = None
         if tc.sync_algorithm in MANUAL_ALGOS:
-            grads, new_ef = sync_gradients(grads, tc, mesh, state.get("ef"),
-                                           sync_plans=sync_plans,
-                                           plan_codes=plan_codes)
-            loss = lax.pmean(loss, dp_axes_of(mesh))
-        lr = lr_fn(state["step"])
-        params, opt, om = adamw_update(grads, state["opt"], state["params"], lr, tc)
+            with jax.named_scope("grad_sync"):
+                grads, new_ef = sync_gradients(grads, tc, mesh, state.get("ef"),
+                                               sync_plans=sync_plans,
+                                               plan_codes=plan_codes)
+                loss = lax.pmean(loss, dp_axes_of(mesh))
+        with jax.named_scope("optimizer"):
+            lr = lr_fn(state["step"])
+            params, opt, om = adamw_update(grads, state["opt"], state["params"],
+                                           lr, tc)
         new_state = {"params": params, "opt": opt, "step": state["step"] + 1}
         if "ef" in state:
             new_state["ef"] = new_ef if new_ef is not None else state["ef"]

@@ -5,6 +5,11 @@ function of the step index and the train state carries its own step counter,
 so ``Trainer.run()`` after a crash (or an ``InjectedFailure``) resumes from
 the latest checkpoint and produces bit-identical results to an uninterrupted
 run — asserted by tests/test_fault_tolerance.py.
+
+Each stretch of a step runs in a host span named in
+``repro.runtime.tracing.SPANS``, and the step call in the trainer's compile
+counter: ``Trainer.compiles`` / ``compile_s``, and ``compiles`` in each
+``history`` entry for its step.
 """
 
 from __future__ import annotations
@@ -22,6 +27,7 @@ from repro.configs.base import ModelConfig, TrainConfig
 from repro.data.pipeline import shard_batch
 from repro.runtime.fault_tolerance import (
     FailureInjector, FaultManager, InjectedFailure, StepWatchdog)
+from repro.runtime.tracing import CompileCounter, span
 from .train_step import (
     abstract_train_state, make_train_state, make_train_step,
     train_state_specs)
@@ -88,6 +94,8 @@ class Trainer:
         self._plan_codes = (None if self.controller is None
                             else self.controller.arrays())
         self._ckpt_requested = False
+        self._compile = CompileCounter()
+        self._stepped = False
         self.history: list[dict] = []
         if self.fault_manager is not None:
             self.fault_manager.attach(self.replan)
@@ -102,8 +110,9 @@ class Trainer:
         if callable(policy):
             policy(event)
             return
-        log.warning("straggler at step %d: %.3fs vs median %.3fs",
-                    event.step, event.duration_s, event.median_s)
+        log.warning("straggler at step %d: %.3fs vs median %.3fs "
+                    "(%.3fs compiling)", event.step, event.duration_s,
+                    event.median_s, event.compile_s)
         if policy == "checkpoint":
             self._ckpt_requested = True
 
@@ -157,41 +166,67 @@ class Trainer:
                 if restarts > self.options.max_restarts:
                     raise
 
+    @property
+    def compiles(self) -> int:
+        """Compilations made by this trainer's step calls, cumulative."""
+        return self._compile.compiles
+
+    @property
+    def compile_s(self) -> float:
+        """Their seconds: tracing, lowering and the backend's compile or
+        persistent-cache load (``repro.runtime.tracing``)."""
+        return self._compile.seconds
+
     def _run_inner(self, total: int):
         state = self.init_or_restore()
         step = int(jax.device_get(state["step"]))
         while step < total:
-            if self.fault_manager is not None:
-                # primary replan path: telemetry -> hysteresis -> mask
-                # (DESIGN.md §14); infeasible proposals keep the previous
-                # plan per the manager's ReplanPolicy
-                self.fault_manager.on_step(step)
-            if self.injector is not None:
-                self.injector.check(step)
-                mask = self.injector.degradation(step)
-                if mask is not None:
-                    self.replan(mask)
-            host_batch = self.source.batch(step)
-            batch = shard_batch(host_batch, self.mesh)
-            self.watchdog.start()
-            if self._plan_codes is not None:
-                state, metrics = self._step_fn(state, batch, self._plan_codes)
-            else:
-                state, metrics = self._step_fn(state, batch)
-            jax.block_until_ready(metrics["loss"])
-            dt = self.watchdog.stop(step)
-            step += 1
-            if step % self.options.log_every == 0 or step == total:
-                m = {k: float(jax.device_get(v)) for k, v in metrics.items()}
-                m.update(step=step, sec_per_step=dt)
-                self.history.append(m)
-                log.info("step %d loss %.4f (%.2fs)", step, m["loss"], dt)
-            if self._ckpt_requested:
-                self._ckpt_requested = False
-                log.warning("straggler policy: forcing early checkpoint at "
-                            "step %d", step)
-                self.ckpt.save(step, state)
-            if step % self.options.ckpt_every == 0 or step == total:
-                self.ckpt.save(step, state)
-        self.ckpt.wait()
+            with span("trainer.control", step=step):
+                if self.fault_manager is not None:
+                    # primary replan path: telemetry -> hysteresis -> mask
+                    # (DESIGN.md §14); infeasible proposals keep the
+                    # previous plan per the manager's ReplanPolicy
+                    self.fault_manager.on_step(step)
+                if self.injector is not None:
+                    self.injector.check(step)
+                    mask = self.injector.degradation(step)
+                    if mask is not None:
+                        self.replan(mask)
+            with span("trainer.input", step=step):
+                host_batch = self.source.batch(step)
+            with span("trainer.shard_batch", step=step):
+                batch = shard_batch(host_batch, self.mesh)
+            compiles, compile_s = self.compiles, self.compile_s
+            with span("trainer.dispatch", step=step), self._compile:
+                self.watchdog.start()
+                if self._plan_codes is not None:
+                    state, metrics = self._step_fn(state, batch, self._plan_codes)
+                else:
+                    state, metrics = self._step_fn(state, batch)
+            with span("trainer.wait", step=step):
+                jax.block_until_ready(metrics["loss"])
+            with span("trainer.record", step=step):
+                compiles = self.compiles - compiles
+                compile_s = self.compile_s - compile_s
+                if compiles and self._stepped:
+                    log.warning("%d compile(s) at step %d (%.2fs)", compiles,
+                                step, compile_s)
+                self._stepped = True
+                dt = self.watchdog.stop(step, compile_s)
+                step += 1
+                if step % self.options.log_every == 0 or step == total:
+                    m = {k: float(jax.device_get(v)) for k, v in metrics.items()}
+                    m.update(step=step, sec_per_step=dt, compiles=compiles)
+                    self.history.append(m)
+                    log.info("step %d loss %.4f (%.2fs)", step, m["loss"], dt)
+            with span("trainer.checkpoint", step=step):
+                if self._ckpt_requested:
+                    self._ckpt_requested = False
+                    log.warning("straggler policy: forcing early checkpoint "
+                                "at step %d", step)
+                    self.ckpt.save(step, state)
+                if step % self.options.ckpt_every == 0 or step == total:
+                    self.ckpt.save(step, state)
+        with span("trainer.checkpoint", step=step):
+            self.ckpt.wait()
         return state
