@@ -6,4 +6,6 @@
 #   quant             int8 block quantize / fused dequant-add (compressed sync)
 # Kernels are TPU targets.  The CPU tests pass interpret=True explicitly and
 # sweep shapes/dtypes against the oracles; tests/test_chip_compile.py compiles
-# the quant kernels for a described v5e chip.
+# the quant kernels for a described v5e chip.  The model's training attention
+# on a TPU is JAX's own splash attention (models.layers.fused_causal_attention),
+# not flash_attention here.

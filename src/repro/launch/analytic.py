@@ -24,7 +24,11 @@ import jax
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import api as mapi
 
-QB, KVB = 512, 1024   # blocked_attention defaults (keep in sync with layers.py)
+# blocked_attention's default blocks (keep in sync with layers.py): the jnp
+# path, which every compile off the TPU takes; on a TPU, causal training
+# attention runs layers.fused_causal_attention, a custom call that carries
+# no cost estimate
+QB, KVB = 512, 1024
 
 
 # ---------------------------------------------------------------------------

@@ -6,9 +6,12 @@ Conventions:
     stored in the config's param dtype and cast at use.
   * attention tensors use [batch, seq, heads, head_dim] at rest and
     [batch, heads, seq, head_dim] inside kernels.
-  * every sequence-quadratic op goes through :func:`blocked_attention`
-    (online-softmax flash pattern) so the 32k prefill shapes never
-    materialize an S×S score matrix.
+  * no sequence-quadratic op materializes an S×S score matrix.  Causal
+    self-attention without a cache or a window runs, on a TPU, as one fused
+    Pallas kernel forward and backward (:func:`fused_causal_attention`);
+    every other attention, and every attention off the TPU, goes through
+    :func:`blocked_attention` (online-softmax flash pattern) or
+    :func:`decode_attention`.
 """
 
 from __future__ import annotations
@@ -19,8 +22,11 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+from repro.runtime import tracing
 
 Init = jax.nn.initializers.normal
 
@@ -62,14 +68,18 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, H, D]; positions: [B, S] (int)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scale: float = 1.0) -> jax.Array:
+    """x: [B, S, H, D]; positions: [B, S] (int).  ``scale`` multiplies the
+    rotated x in f32, before its one cast back to x's dtype."""
     d = x.shape[-1]
     freqs = rope_freqs(d, theta)                       # [D/2]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if scale != 1.0:
+        out = out * scale
     return out.astype(x.dtype)
 
 
@@ -199,6 +209,82 @@ def decode_attention(
 
 
 # ---------------------------------------------------------------------------
+# fused causal attention (TPU) — JAX's splash attention kernel
+# ---------------------------------------------------------------------------
+
+# the name splash attention gives its forward kernel in the compiled program
+FUSED_KERNEL = "splash_mqa_fwd"
+
+
+def fused_attention_blocks(seq_len: int) -> splash.BlockSizes | None:
+    """The kernel's tiles for one KV head's causal self-attention over
+    ``seq_len`` tokens, or None where the sequence is no multiple of them.
+
+    Chosen on a TPU v5e at both training cells' shapes (2,048 tokens with
+    head_dim 128, 4,096 with 64), timing the forward, its remat recompute
+    and the backward together: 1,024-row q and kv tiles, the forward's
+    scores taken 512 keys at a time, and the fused backward (dq, dk, dv in
+    one kernel) beat every other tiling tried and the separate dq kernel at
+    both, so the head size does not enter the choice."""
+    bq = bkv = min(1024, seq_len)
+    if bkv % 128 or seq_len % bkv:
+        return None
+    return splash.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=min(512, bkv),
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
+        use_fused_bwd_kernel=True)
+
+
+def fused_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                           interpret: bool = False) -> jax.Array:
+    """Causal self-attention as one Pallas kernel with its own backward.
+
+    q [B, S, H, D], already scaled by 1/sqrt(D); k, v [B, S, K, D] with
+    K | H.  Each (batch, KV head) runs splash attention's MQA form over its
+    group of H/K query heads, so K/V are never broadcast.  Score and
+    probability tiles live in VMEM only; tiles above the diagonal are
+    skipped; the backward recomputes them from q, k, v and the saved f32
+    row log-sum-exp.  Matmul operands are q's dtype, softmax statistics and
+    accumulators f32.  ``S`` must be a multiple of
+    :func:`fused_attention_blocks`' tiles.
+    """
+    b, s, h, d = q.shape
+    kh = k.shape[2]
+    g = h // kh
+    kernel = splash.make_splash_mqa_single_device(
+        splash.MultiHeadMask([splash.CausalMask((s, s))] * g),
+        block_sizes=fused_attention_blocks(s), interpret=interpret)
+    qt = q.reshape(b, s, kh, g, d).transpose(0, 2, 3, 1, 4)     # [B,K,G,S,D]
+    kt, vt = (x.transpose(0, 2, 1, 3) for x in (k, v))          # [B,K,S,D]
+    out = jax.vmap(jax.vmap(kernel))(qt, kt, vt)                # [B,K,G,S,D]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, v.shape[-1])
+
+
+def _fused_route(batch: int, seq_len: int):
+    """:func:`fused_causal_attention` as this call can run it on a TPU, or
+    None.  The kernel is one device's program: where the installed mesh
+    leaves only the batch axes to GSPMD, it runs under ``shard_map`` over
+    them; where it leaves any other axis of more than one device (tensor
+    parallelism), GSPMD would replicate the custom call, so None."""
+    from repro.parallel import context as pctx
+
+    if fused_attention_blocks(seq_len) is None:
+        return None
+    mesh = pctx.get_mesh()
+    manual = pctx._manual_axes()
+    auto = () if mesh is None else tuple(
+        a for a in mesh.axis_names if mesh.shape[a] > 1 and a not in manual)
+    if not auto:
+        return fused_causal_attention
+    if not set(auto) <= {"pod", "data"} or batch % math.prod(
+            mesh.shape[a] for a in auto):
+        return None
+    spec = P(auto)
+    return jax.shard_map(fused_causal_attention, in_specs=(spec, spec, spec),
+                         out_specs=spec, axis_names=set(auto), check_vma=False)
+
+
+# ---------------------------------------------------------------------------
 # standard GQA attention layer (with optional cache)
 # ---------------------------------------------------------------------------
 
@@ -247,17 +333,22 @@ def attention_apply(
     # GSPMD re-shards per kv block inside the scan (measured 6.4 GB/layer of
     # all-reduce on the 67B prefill cell; §Perf iteration 11)
     q = pctx.constrain(q, pctx.BATCH, None, pctx.MODEL, None)
+    rope = cfg.pos_embed == "rope" and kv_override is None
     if kv_override is None:
         k = _proj(x, p["wk"], p.get("bk")).reshape(b, s, cfg.n_kv_heads, hd)
         v = _proj(x, p["wv"], p.get("bv")).reshape(b, s, cfg.n_kv_heads, hd)
-        if cfg.pos_embed == "rope":
-            q = apply_rope(q, positions, cfg.rope_theta)
+        if rope:
             k = apply_rope(k, positions, cfg.rope_theta)
         kv_spec = pctx.MODEL if cfg.n_kv_heads % pctx.model_axis_size() == 0 else None
         k = pctx.constrain(k, pctx.BATCH, None, kv_spec, None)
         v = pctx.constrain(v, pctx.BATCH, None, kv_spec, None)
     else:
         k, v = kv_override
+
+    def queries(scale: float = 1.0):
+        if rope:
+            return apply_rope(q, positions, cfg.rope_theta, scale)
+        return q if scale == 1.0 else (q.astype(jnp.float32) * scale).astype(q.dtype)
 
     new_cache = None
     if cache is not None and kv_override is None:
@@ -267,12 +358,23 @@ def attention_apply(
                                              cache_index, axis=1)
         new_cache = {"k": kc, "v": vc}
         if s == 1:
-            out = decode_attention(q, kc, vc, cache_index + 1, window=window)
+            tracing.take_path("attention", "decode")
+            out = decode_attention(queries(), kc, vc, cache_index + 1, window=window)
         else:
-            out = blocked_attention(q, kc[:, : cache_index + s], vc[:, : cache_index + s],
-                                    causal=causal, q_offset=cache_index, window=window)
+            tracing.take_path("attention", "blocked")
+            out = blocked_attention(queries(), kc[:, : cache_index + s],
+                                    vc[:, : cache_index + s], causal=causal,
+                                    q_offset=cache_index, window=window)
+    elif (kv_override is None and causal and window is None
+          and (fused := _fused_route(b, s)) is not None):
+        # the kernel takes no scale: fold 1/sqrt(hd) into q's f32 rope
+        tracing.take_path("attention", "kernel")
+        out = lax.platform_dependent(
+            tpu=lambda: fused(queries(1.0 / math.sqrt(hd)), k, v),
+            default=lambda: blocked_attention(queries(), k, v, causal=True))
     else:
-        out = blocked_attention(q, k, v, causal=causal, window=window)
+        tracing.take_path("attention", "blocked")
+        out = blocked_attention(queries(), k, v, causal=causal, window=window)
     y = out.reshape(b, s, cfg.n_heads * hd) @ p["wo"].astype(x.dtype)
     return y, new_cache
 
@@ -363,6 +465,8 @@ def mla_apply(
     else:
         c_kv_all, k_rope_all = c_kv, k_rope
 
+    tracing.take_path("attention", "decode" if s == 1 and cache is not None
+                      else "blocked")
     if s == 1 and cache is not None:
         # ---- absorbed decode (MLA's raison d'etre): score & combine in the
         # r-dim latent space; per-head K/V are never materialized over the

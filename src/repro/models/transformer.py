@@ -21,6 +21,7 @@ from jax import lax
 
 from repro.configs.base import ModelConfig
 from repro.parallel import context as pctx
+from repro.runtime import tracing
 from . import layers as L
 
 
@@ -156,7 +157,7 @@ def forward(
             body, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
 
     scan_cache = None if cache is None else cache["scan"]
-    with jax.named_scope("layers"):
+    with jax.named_scope("layers"), tracing.repeated(cfg.n_layers - cfg.first_k_dense):
         (x, aux_total), new_scan_cache = lax.scan(
             body, (x, aux_total), (params["layers"], scan_cache))
 

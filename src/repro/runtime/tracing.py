@@ -17,10 +17,18 @@ cache's retrieval. Its seconds are the union of the spans of those events
 and of the tracing and lowering that led to it (``COMPILE_TIME_EVENTS``):
 a jit called while another is traced records its own trace inside the
 outer one, and a sum of their durations would count it twice.
+
+``PathCounter`` counts, while the step is traced, which path each call of
+a layer took where the model chooses between implementations in Python
+(``take_path(layer, path)``). A call traced inside ``repeated(n)``, as the
+decoder's layer scan traces its one body for n layers, counts n times.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import math
 import threading
 
 import jax
@@ -59,30 +67,67 @@ class CompileCounter:
         self._spans.append((start, end))
 
     def __enter__(self):
-        _open_counters().append(self)
+        _stack("open").append(self)
         return self
 
     def __exit__(self, *exc):
-        _open_counters().pop()
+        _stack("open").pop()
 
 
 _local = threading.local()
 counted = CompileCounter()
 
 
-def _open_counters() -> list:
-    if not hasattr(_local, "open"):
-        _local.open = []
-    return _local.open
+def _stack(name: str) -> list:
+    """This thread's list ``name``: open counters, or ``repeated`` counts."""
+    if not hasattr(_local, name):
+        setattr(_local, name, [])
+    return getattr(_local, name)
 
 
 def _on_span(event: str, start: float, end: float, **kwargs) -> None:
     if event not in COMPILE_TIME_EVENTS:
         return
-    stack = _open_counters()
+    stack = _stack("open")
     if stack:
         stack[-1].add(event, start, end)
         counted.add(event, start, end)
 
 
 monitoring.register_event_time_span_listener(_on_span)
+
+
+class PathCounter:
+    """Calls of each layer by the path they took, cumulative over every
+    trace made while the counter was open on the thread that opened it:
+    ``counts[layer, path]``."""
+
+    def __init__(self):
+        self.counts: collections.Counter = collections.Counter()
+
+    def __enter__(self):
+        _stack("paths").append(self)
+        return self
+
+    def __exit__(self, *exc):
+        _stack("paths").pop()
+
+
+def take_path(layer: str, path: str) -> None:
+    """One traced call of ``layer`` took ``path``: counted by the counter
+    opened last on this thread, as many times as the enclosing
+    ``repeated`` scopes run it."""
+    stack = _stack("paths")
+    if stack:
+        stack[-1].counts[layer, path] += math.prod(_stack("repeats"))
+
+
+@contextlib.contextmanager
+def repeated(n: int):
+    """What is traced inside runs ``n`` times a call (a scan's body)."""
+    reps = _stack("repeats")
+    reps.append(n)
+    try:
+        yield
+    finally:
+        reps.pop()

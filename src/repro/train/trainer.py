@@ -9,11 +9,14 @@ run — asserted by tests/test_fault_tolerance.py.
 Each stretch of a step runs in a host span named in
 ``repro.runtime.tracing.SPANS``, and the step call in the trainer's compile
 counter: ``Trainer.compiles`` / ``compile_s``, and ``compiles`` in each
-``history`` entry for its step.
+``history`` entry for its step. Once the step has compiled,
+``Trainer.attention_paths`` says how many of its attention layers run the
+fused kernel and how many ``blocked_attention`` (logged at INFO).
 """
 
 from __future__ import annotations
 
+import collections
 import logging
 from dataclasses import dataclass, field
 from functools import partial
@@ -27,7 +30,8 @@ from repro.configs.base import ModelConfig, TrainConfig
 from repro.data.pipeline import shard_batch
 from repro.runtime.fault_tolerance import (
     FailureInjector, FaultManager, InjectedFailure, StepWatchdog)
-from repro.runtime.tracing import CompileCounter, span
+from repro.models.layers import FUSED_KERNEL
+from repro.runtime.tracing import CompileCounter, PathCounter, span
 from .train_step import (
     abstract_train_state, make_train_state, make_train_step,
     train_state_specs)
@@ -95,6 +99,8 @@ class Trainer:
                             else self.controller.arrays())
         self._ckpt_requested = False
         self._compile = CompileCounter()
+        self._paths = PathCounter()
+        self.attention_paths: dict[str, int] | None = None
         self._stepped = False
         self.history: list[dict] = []
         if self.fault_manager is not None:
@@ -177,6 +183,21 @@ class Trainer:
         persistent-cache load (``repro.runtime.tracing``)."""
         return self._compile.seconds
 
+    def _count_attention_paths(self, *args) -> dict[str, int]:
+        """The step's attention layers by path: as traced (each layer's
+        choice in Python), then as compiled. A layer traced onto the kernel
+        runs ``blocked_attention`` where the compiled step holds no kernel
+        (a platform other than the TPU). ``args`` are the step call's;
+        lowering them again hits the executable the call compiled."""
+        counts = collections.Counter({path: n for (layer, path), n
+                                      in self._paths.counts.items()
+                                      if layer == "attention"})
+        if counts["kernel"]:
+            text = self._step_fn.lower(*args).compile().as_text()
+            if FUSED_KERNEL not in text:
+                counts["blocked"] += counts.pop("kernel")
+        return dict(counts)
+
     def _run_inner(self, total: int):
         state = self.init_or_restore()
         step = int(jax.device_get(state["step"]))
@@ -197,12 +218,11 @@ class Trainer:
             with span("trainer.shard_batch", step=step):
                 batch = shard_batch(host_batch, self.mesh)
             compiles, compile_s = self.compiles, self.compile_s
-            with span("trainer.dispatch", step=step), self._compile:
+            args = (batch,) if self._plan_codes is None else (batch, self._plan_codes)
+            with (span("trainer.dispatch", step=step), self._compile,
+                  self._paths):
                 self.watchdog.start()
-                if self._plan_codes is not None:
-                    state, metrics = self._step_fn(state, batch, self._plan_codes)
-                else:
-                    state, metrics = self._step_fn(state, batch)
+                state, metrics = self._step_fn(state, *args)
             with span("trainer.wait", step=step):
                 jax.block_until_ready(metrics["loss"])
             with span("trainer.record", step=step):
@@ -213,6 +233,10 @@ class Trainer:
                                 step, compile_s)
                 self._stepped = True
                 dt = self.watchdog.stop(step, compile_s)
+                if compiles and self.attention_paths is None:
+                    self.attention_paths = self._count_attention_paths(state, *args)
+                    log.info("attention layers a step, by path: %s",
+                             self.attention_paths)
                 step += 1
                 if step % self.options.log_every == 0 or step == total:
                     m = {k: float(jax.device_get(v)) for k, v in metrics.items()}
