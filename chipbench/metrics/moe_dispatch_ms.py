@@ -1,0 +1,10 @@
+"""Device self time of the program's ``moe_dispatch`` scope (routing,
+gathers into the experts' slots, and their backward) per traced step,
+averaged over the devices (``scopes.reduce``). Nothing to read where the
+trace has no op under that scope."""
+
+from chipbench import scopes
+
+
+def read(rec):
+    return scopes.scope_ms(rec["trace"], "moe_dispatch")

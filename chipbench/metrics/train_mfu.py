@@ -1,11 +1,11 @@
-"""Model FLOPs per token (``chipbench/flops.py``, no recomputation) times the
-window's tokens per second, over the chips' bf16 peak from
-``chipbench/peaks.json``, in percent."""
+"""Model FLOPs per token, as the configuration's reference module counts
+them (``train_flops_per_token``, no recomputation), times the window's
+tokens per second, over the chips' bf16 peak from ``chipbench/peaks.json``,
+in percent."""
 
 import json
 
-from chipbench import BENCH
-from chipbench.flops import train_flops_per_token
+from chipbench import BENCH, reference
 
 
 def read(rec):
@@ -13,5 +13,6 @@ def read(rec):
     kind = rec["device_kind"]
     if kind not in peaks:
         raise KeyError(f"no peak for device kind {kind!r} in peaks.json")
-    flops = train_flops_per_token(rec["config_file"], rec["seq_len"])
+    cfg = rec["config_file"]
+    flops = reference.load(cfg).train_flops_per_token(cfg, rec["seq_len"])
     return 100.0 * flops * rec["tokens_per_s"] / (rec["chips"] * peaks[kind]["bf16_flops_per_s"])

@@ -1,4 +1,4 @@
-"""Plain float32 reference of the decoders the benchmark trains.
+"""Plain float32 reference of the GQA decoders the benchmark trains.
 
 Written from the published descriptions of Qwen2 and Granite 3.0 MoE and
 from nothing in ``src/``: a GQA decoder with optional QKV bias, rotary
@@ -6,8 +6,8 @@ positions (rotate-half), RMSNorm, a SwiGLU MLP or a top-k mixture of SwiGLU
 experts, and an embedding tied to the output head; the loss is the mean
 next-token cross entropy plus the router's load-balancing term. One AdamW
 step with global-norm clipping and linear warm-up follows the published
-AdamW. Departures from the published models are listed in each
-configuration file under ``departures``.
+AdamW (``common.adamw_step``). Departures from the published models are
+listed in each configuration file under ``departures``.
 
 Everything runs in float32 at ``Precision.HIGHEST``. ``prec="fp8"`` is the
 control: every matmul operand is rounded to float8 e4m3 with a per-tensor
@@ -23,6 +23,7 @@ a time.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import zlib
 from dataclasses import dataclass
@@ -30,15 +31,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-HIGHEST = lax.Precision.HIGHEST
-Q_CHUNK = 512        # query rows per attention chunk
-LOSS_CHUNK = 512     # tokens per chunk of the output head
-ADAM_EPS = 1e-8
-FP8_MAX = 448.0      # largest finite float8_e4m3fn
+from chipbench.reference import common
+from chipbench.reference.common import mm
 
 
 @dataclass(frozen=True)
@@ -75,20 +71,76 @@ class Arch:
             init_std=float(c["initializer_range"]))
 
 
-@dataclass(frozen=True)
-class Optim:
-    lr: float
-    warmup_steps: int
-    weight_decay: float
-    grad_clip: float
-    b1: float
-    b2: float
+def program_config(cfg_file: dict):
+    """The program's configuration of this file: its registry entry with the
+    file's depth, after checking every width, and the tied head, against
+    the file. The one function here that reads the program."""
+    from repro.configs import registry
 
-    @classmethod
-    def from_traffic(cls, t: dict) -> "Optim":
-        o = t["train_config"]
-        return cls(o["lr"], o["warmup_steps"], o["weight_decay"],
-                   o["grad_clip"], o["b1"], o["b2"])
+    full = registry.get(cfg_file["registry_id"])
+    cfg = dataclasses.replace(full, n_layers=cfg_file["num_hidden_layers"])
+    a = Arch.from_config(cfg_file)
+    got = dict(vocab=cfg.vocab_size, d=cfg.d_model, heads=cfg.n_heads,
+               kv_heads=cfg.n_kv_heads, head_dim=cfg.resolved_head_dim,
+               qkv_bias=cfg.qkv_bias, rope_theta=cfg.rope_theta,
+               eps=cfg.norm_eps,
+               ffn=cfg.moe.d_expert if cfg.moe else cfg.d_ff,
+               experts=cfg.moe.n_experts if cfg.moe else 0,
+               top_k=cfg.moe.top_k if cfg.moe else 0)
+    if cfg.moe:
+        got.update(capacity_factor=cfg.moe.capacity_factor,
+                   aux_weight=cfg.moe.router_aux_weight)
+    want = {k: getattr(a, k) for k in got}
+    got["tie_embeddings"] = cfg.tie_embeddings
+    want["tie_embeddings"] = cfg_file["tie_word_embeddings"]
+    differ = sorted(k for k in got if got[k] != want[k])
+    if differ:
+        raise ValueError(f"{cfg_file['registry_id']}: {', '.join(differ)} differ; "
+                         f"the program runs {got}, the configuration file states {want}")
+    if not cfg.tie_embeddings:
+        raise ValueError(f"{cfg_file['registry_id']}: tie_embeddings is false; this "
+                         "reference's output head is its embedding")
+    return cfg
+
+
+# ---------------------------------------------------------------- FLOPs
+#
+# The arithmetic of the repository's analytic model (6 x active parameters
+# per token, plus the causal half of attention's score and value products,
+# three times over for the forward and backward passes), kept here so that
+# the yardstick does not move when the program does. Recomputation is not
+# counted. Active parameters are the matrices a token passes through:
+# attention, the dense MLP or the router plus ``top_k / experts`` of the
+# routed experts, the norms and biases, and the tied embedding once, as the
+# output head (its use as a lookup table costs no FLOPs).
+
+def active_params(c: dict) -> float:
+    d, L = c["hidden_size"], c["num_hidden_layers"]
+    h, kv = c["num_attention_heads"], c["num_key_value_heads"]
+    hd = c.get("head_dim") or d // h
+    attn = 2 * d * h * hd + 2 * d * kv * hd
+    if c["qkv_bias"]:
+        attn += h * hd + 2 * kv * hd
+    experts = c.get("num_local_experts", 0)
+    if experts:
+        ffn = d * experts + 3 * d * c["intermediate_size"] * c["num_experts_per_tok"]
+    else:
+        ffn = 3 * d * c["intermediate_size"]
+    per_layer = attn + ffn + 2 * d
+    head = c["vocab_size"] * d
+    return float(L * per_layer + d + head)
+
+
+def attention_flops_per_token(c: dict, seq_len: int) -> float:
+    """Forward and backward score and value products of one token, over the
+    causal half of a ``seq_len`` context."""
+    h = c["num_attention_heads"]
+    hd = c.get("head_dim") or c["hidden_size"] // h
+    return 3.0 * c["num_hidden_layers"] * 2 * h * (0.5 * seq_len) * (hd + hd)
+
+
+def train_flops_per_token(c: dict, seq_len: int) -> float:
+    return 6.0 * active_params(c) + attention_flops_per_token(c, seq_len)
 
 
 # ---------------------------------------------------------------- weights
@@ -113,11 +165,6 @@ def weight_shapes(a: Arch) -> dict:
             "final_norm": {"scale": (d,)}}
 
 
-def seed_key(seed: int):
-    """A PRNG key from a seed of up to 64 bits."""
-    return jax.random.fold_in(jax.random.key(seed & 0xFFFFFFFF), seed >> 32)
-
-
 def init_weights(a: Arch, key) -> dict:
     """Normal(0, initializer_range) matrices and biases, unit norm scales.
     Each leaf draws from its own key, folded in by its path, so a leaf's
@@ -136,66 +183,12 @@ def init_weights(a: Arch, key) -> dict:
     return jax.tree_util.tree_unflatten(tree, out)
 
 
-@jax.jit
-def leaf_norms(tree):
-    return [jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
-            for x in jax.tree.leaves(tree)]
-
-
 # ---------------------------------------------------------------- layers
 
-def _fake_fp8(x):
-    scale = lax.stop_gradient(jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / FP8_MAX)
-    q = (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
-    return x + lax.stop_gradient(q - x)
-
-
-def _mm(spec, x, y, prec):
-    if prec == "fp8":
-        x, y = _fake_fp8(x), _fake_fp8(y)
-    return jnp.einsum(spec, x, y, precision=HIGHEST,
-                      preferred_element_type=jnp.float32)
-
-
-def _rms(x, scale, eps):
-    return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def _rope(x, theta):
-    """Rotate-half rotary embedding of x [B, S, H, D] at positions 0..S-1."""
-    s, d = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
-    ang = jnp.asarray(np.arange(s)[:, None] * inv[None, :], jnp.float32)
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _attention(q, k, v, prec):
-    """Causal GQA attention, one chunk of queries at a time."""
-    b, s, h, d = q.shape
-    g = h // k.shape[2]
-    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-    qc = min(Q_CHUNK, s)
-    nq = s // qc
-
-    @jax.checkpoint
-    def chunk(args):
-        i, qb = args
-        sc = _mm("bqhd,bkhd->bhqk", qb, k, prec) / math.sqrt(d)
-        causal = (i * qc + jnp.arange(qc))[:, None] >= jnp.arange(s)[None, :]
-        p = jax.nn.softmax(jnp.where(causal, sc, -jnp.inf), axis=-1)
-        return _mm("bhqk,bkhd->bqhd", p, v, prec)
-
-    qs = q.reshape(b, nq, qc, h, d).swapaxes(0, 1)
-    out = lax.map(chunk, (jnp.arange(nq), qs))
-    return out.swapaxes(0, 1).reshape(b, s, h, d)
-
-
 def _mlp(w, x, prec):
-    gate = _mm("bsd,df->bsf", x, w["w_gate"], prec)
-    up = _mm("bsd,df->bsf", x, w["w_up"], prec)
-    return _mm("bsf,fd->bsd", jax.nn.silu(gate) * up, w["w_down"], prec)
+    gate = mm("bsd,df->bsf", x, w["w_gate"], prec)
+    up = mm("bsd,df->bsf", x, w["w_up"], prec)
+    return mm("bsf,fd->bsd", jax.nn.silu(gate) * up, w["w_down"], prec)
 
 
 def _moe(a: Arch, w, x, prec):
@@ -206,7 +199,7 @@ def _moe(a: Arch, w, x, prec):
     b, s, d = x.shape
     t, e, k = b * s, a.experts, a.top_k
     xt = x.reshape(t, d)
-    probs = jax.nn.softmax(_mm("td,de->te", xt, w["router"], prec), axis=-1)
+    probs = jax.nn.softmax(mm("td,de->te", xt, w["router"], prec), axis=-1)
     top_p, top_e = lax.top_k(probs, k)
     gates = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
     onehot = jax.nn.one_hot(top_e.reshape(t * k), e, dtype=jnp.int32)
@@ -219,8 +212,8 @@ def _moe(a: Arch, w, x, prec):
     @jax.checkpoint
     def expert(y, ew):
         wg, wu, wd, c = ew
-        h = jax.nn.silu(_mm("td,df->tf", xt, wg, prec)) * _mm("td,df->tf", xt, wu, prec)
-        return y + c[:, None] * _mm("tf,fd->td", h, wd, prec), None
+        h = jax.nn.silu(mm("td,df->tf", xt, wg, prec)) * mm("td,df->tf", xt, wu, prec)
+        return y + c[:, None] * mm("tf,fd->td", h, wd, prec), None
 
     y, _ = lax.scan(expert, jnp.zeros((t, d), jnp.float32),
                     (w["w_gate"], w["w_up"], w["w_down"], combine.T))
@@ -232,18 +225,18 @@ def _moe(a: Arch, w, x, prec):
 def _layer(a: Arch, prec, x, w):
     b, s, _ = x.shape
     at = w["attn"]
-    h = _rms(x, w["ln1"]["scale"], a.eps)
-    q = _mm("bsd,de->bse", h, at["wq"], prec)
-    k = _mm("bsd,de->bse", h, at["wk"], prec)
-    v = _mm("bsd,de->bse", h, at["wv"], prec)
+    h = common.rms(x, w["ln1"]["scale"], a.eps)
+    q = mm("bsd,de->bse", h, at["wq"], prec)
+    k = mm("bsd,de->bse", h, at["wk"], prec)
+    v = mm("bsd,de->bse", h, at["wv"], prec)
     if a.qkv_bias:
         q, k, v = q + at["bq"], k + at["bk"], v + at["bv"]
-    q = _rope(q.reshape(b, s, a.heads, a.head_dim), a.rope_theta)
-    k = _rope(k.reshape(b, s, a.kv_heads, a.head_dim), a.rope_theta)
+    q = common.rope(q.reshape(b, s, a.heads, a.head_dim), a.rope_theta)
+    k = common.rope(k.reshape(b, s, a.kv_heads, a.head_dim), a.rope_theta)
     v = v.reshape(b, s, a.kv_heads, a.head_dim)
-    o = _attention(q, k, v, prec).reshape(b, s, a.heads * a.head_dim)
-    x = x + _mm("bse,ed->bsd", o, at["wo"], prec)
-    h = _rms(x, w["ln2"]["scale"], a.eps)
+    o = common.attention(q, k, v, prec, math.sqrt(a.head_dim))
+    x = x + mm("bse,ed->bsd", o.reshape(b, s, a.heads * a.head_dim), at["wo"], prec)
+    h = common.rms(x, w["ln2"]["scale"], a.eps)
     if a.experts:
         m, aux = _moe(a, w["moe"], h, prec)
     else:
@@ -263,104 +256,22 @@ def block_loss(a: Arch, prec, w, tokens, labels):
         return x, aux
 
     x, aux = lax.scan(layer, x, w["layers"])
-    h = _rms(x, w["final_norm"]["scale"], a.eps).reshape(-1, a.d)
-    lab = labels.reshape(-1)
-    n = h.shape[0]
-    c = min(LOSS_CHUNK, n)
-
-    @jax.checkpoint
-    def nll(args):
-        hc, lc = args
-        logits = _mm("td,vd->tv", hc, emb, prec)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        return jnp.sum(lse - jnp.take_along_axis(logits, lc[:, None], -1)[:, 0])
-
-    sums = lax.map(nll, (h.reshape(n // c, c, a.d), lab.reshape(n // c, c)))
-    return jnp.sum(sums) / n + jnp.sum(aux)
+    h = common.rms(x, w["final_norm"]["scale"], a.eps).reshape(-1, a.d)
+    return common.mean_nll(h, labels.reshape(-1), emb, prec) + jnp.sum(aux)
 
 
 # ---------------------------------------------------------------- training
 
-def make_grad_fn(a: Arch, prec: str, devices):
-    """(weights, tokens [nb, rows, S], labels) -> (mean loss, mean grads)
-    over the nb blocks, the blocks spread over ``devices`` and summed."""
-    mesh = Mesh(np.array(devices), ("blocks",))
-    vg = jax.value_and_grad(partial(block_loss, a, prec))
-
-    def local(w, toks, labs):
-        def body(acc, blk):
-            l, g = vg(w, *blk)
-            return (acc[0] + l, jax.tree.map(jnp.add, acc[1], g)), None
-
-        zero = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, w))
-        (l, g), _ = lax.scan(body, zero, (toks, labs))
-        return lax.psum(l, "blocks"), lax.psum(g, "blocks")
-
-    summed = jax.shard_map(local, mesh=mesh,
-                           in_specs=(P(), P("blocks"), P("blocks")),
-                           out_specs=(P(), P()), check_vma=False)
-
-    @jax.jit
-    def fn(w, toks, labs):
-        l, g = summed(w, toks, labs)
-        nb = toks.shape[0]
-        return l / nb, jax.tree.map(lambda x: x / nb, g)
-
-    return fn, NamedSharding(mesh, P()), NamedSharding(mesh, P("blocks"))
-
-
-@partial(jax.jit, static_argnums=(4,), donate_argnums=(0, 1, 2))
-def adamw_step(w, m, v, g, o: Optim, t):
-    """AdamW at step index t (0-based): clip by the global norm, linear
-    warm-up of the learning rate, bias-corrected moments, decoupled weight
-    decay on every leaf. Returns the clipped gradient too."""
-    gn = jnp.sqrt(sum(jnp.sum(x * x) for x in jax.tree.leaves(g)))
-    g = jax.tree.map(lambda x: x * jnp.minimum(1.0, o.grad_clip / jnp.maximum(gn, 1e-12)), g)
-    lr = o.lr * t / o.warmup_steps
-    n = t + 1.0
-    bc1, bc2 = 1.0 - o.b1 ** n, 1.0 - o.b2 ** n
-    m = jax.tree.map(lambda m, g: o.b1 * m + (1 - o.b1) * g, m, g)
-    v = jax.tree.map(lambda v, g: o.b2 * v + (1 - o.b2) * g * g, v, g)
-    w = jax.tree.map(
-        lambda w, m, v: w - lr * ((m / bc1) / (jnp.sqrt(v / bc2) + ADAM_EPS)
-                                  + o.weight_decay * w), w, m, v)
-    return w, m, v, leaf_norms(g)
-
-
-def follow(a: Arch, o: Optim, seed: int, batches, devices, prec="f32"):
+def follow(a: Arch, o: common.Optim, seed: int, batches, devices, prec="f32"):
     """Train the reference from the seed's weights over ``batches`` (a list
     of (tokens, labels), each [blocks, rows, S]). Returns the loss of each
     step, the per-leaf norms of the first clipped gradient, and the per-leaf
     norms of the change of the weights after the last step."""
-    assert len(batches) <= o.warmup_steps
-    grad_fn, rep, split = make_grad_fn(a, prec, devices)
-    with jax.default_matmul_precision("highest"):
-        init = jax.jit(init_weights, static_argnums=0, out_shardings=rep)
-        w = init(a, seed_key(seed))
-        m = jax.tree.map(jnp.zeros_like, w)
-        v = jax.tree.map(jnp.zeros_like, w)
-        losses, g1 = [], None
-        for t, (toks, labs) in enumerate(batches):
-            toks = jax.device_put(toks, split)
-            labs = jax.device_put(labs, split)
-            loss, g = grad_fn(w, toks, labs)
-            w, m, v, gn = adamw_step(w, m, v, g, o, float(t))
-            del g
-            losses.append(float(loss))
-            if g1 is None:
-                g1 = [float(x) for x in gn]
-        del m, v
-        dn = delta_norms(w, a, seed)
-    return {"loss": losses, "grad": g1, "delta": dn}
+    return common.follow(partial(block_loss, a, prec), partial(init_weights, a),
+                         o, seed, batches, devices)
 
 
 def delta_norms(w, a: Arch, seed: int) -> list[float]:
     """Per-leaf norm of ``w`` minus the seed's initial weights, the first
     ``a.vocab`` rows of the embedding only (a program may pad it)."""
-    @jax.jit
-    def fn(w, key):
-        w0 = init_weights(a, key)
-        w = dict(w, embed={"tok": w["embed"]["tok"][: a.vocab]})
-        return leaf_norms(jax.tree.map(lambda x, y: x.astype(jnp.float32) - y, w, w0))
-
-    return [float(x) for x in fn(w, seed_key(seed))]
+    return common.delta_norms(w, partial(init_weights, a), seed)

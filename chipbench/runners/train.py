@@ -18,7 +18,6 @@ those steps.
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import gc
 import shutil
 import tempfile
@@ -27,9 +26,9 @@ import time
 import jax
 import jax.numpy as jnp
 
-from chipbench import compare, trace as trace_mod
+from chipbench import compare, reference, scopes, trace as trace_mod
 from chipbench.feed import Feed
-from chipbench.reference import decoder as ref
+from chipbench.reference.common import Optim, leaf_norms, seed_key
 
 
 class WindowClosed(Exception):
@@ -96,29 +95,26 @@ class Clock:
         return self.times[-1] - self.times[0]
 
 
-def program_config(cfg_file: dict):
-    """The program's configuration of this file: the registry entry with the
-    file's depth, after checking that every width agrees with the file."""
-    from repro.configs import registry
+def vocab_padding(ref_shapes, program_shapes, vocab: int):
+    """Per leaf, the ``jnp.pad`` widths that turn the reference's leaf into
+    the program's: none where the shapes agree, zero rows after the
+    ``vocab`` ids where they differ along one axis of ``vocab`` entries
+    alone (the program pads its vocabulary). Any other difference raises."""
+    if jax.tree.structure(ref_shapes) != jax.tree.structure(program_shapes):
+        raise ValueError("the reference's weights are not named and nested as the "
+                         f"program's parameters: {jax.tree.structure(ref_shapes)} "
+                         f"against {jax.tree.structure(program_shapes)}")
 
-    full = registry.get(cfg_file["registry_id"])
-    cfg = dataclasses.replace(full, n_layers=cfg_file["num_hidden_layers"])
-    a = ref.Arch.from_config(cfg_file)
-    got = dict(vocab=cfg.vocab_size, d=cfg.d_model, heads=cfg.n_heads,
-               kv_heads=cfg.n_kv_heads, head_dim=cfg.resolved_head_dim,
-               qkv_bias=cfg.qkv_bias, rope_theta=cfg.rope_theta,
-               eps=cfg.norm_eps,
-               ffn=cfg.moe.d_expert if cfg.moe else cfg.d_ff,
-               experts=cfg.moe.n_experts if cfg.moe else 0,
-               top_k=cfg.moe.top_k if cfg.moe else 0)
-    if cfg.moe:
-        got.update(capacity_factor=cfg.moe.capacity_factor,
-                   aux_weight=cfg.moe.router_aux_weight)
-    want = {k: getattr(a, k) for k in got}
-    if got != want or not cfg.tie_embeddings:
-        raise ValueError(f"{cfg_file['registry_id']}: the program runs {got}, "
-                         f"the configuration file states {want}")
-    return cfg
+    def widths(path, r, p):
+        r, p = r.shape, p.shape
+        differ = [a for a, b in zip(r, p) if a != b]
+        if len(r) == len(p) and differ in ([], [vocab]) and all(a <= b for a, b in zip(r, p)):
+            return tuple((0, b - a) for a, b in zip(r, p))
+        raise ValueError(f"{jax.tree_util.keystr(path)}: the reference's leaf is "
+                         f"{r}, the program's {p}; they may differ along the "
+                         f"vocabulary axis ({vocab} ids) alone")
+
+    return jax.tree_util.tree_map_with_path(widths, ref_shapes, program_shapes)
 
 
 class Job:
@@ -131,10 +127,11 @@ class Job:
         from repro.train.train_step import abstract_train_state
 
         self.cfg_file, self.traffic = spec["config_file"], spec["traffic_file"]
-        self.arch = ref.Arch.from_config(self.cfg_file)
-        self.optim = ref.Optim.from_traffic(self.traffic)
+        self.ref = reference.load(self.cfg_file)
+        self.arch = self.ref.Arch.from_config(self.cfg_file)
+        self.optim = Optim.from_traffic(self.traffic)
         self.devices = devices
-        self.cfg = program_config(self.cfg_file)
+        self.cfg = self.ref.program_config(self.cfg_file)
         self.tc = TrainConfig(**self.traffic["train_config"])
         dims = self.traffic.get("mesh")
         self.mesh = make_mesh(tuple(dims)) if dims else None
@@ -165,14 +162,14 @@ class Job:
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train.train_step import train_state_specs
 
-        abstract, arch = self.abstract, self.arch
-        rows = abstract["params"]["embed"]["tok"].shape[0]
+        abstract, arch, init = self.abstract, self.arch, self.ref.init_weights
+        pad = vocab_padding(jax.eval_shape(lambda k: init(arch, k), seed_key(0)),
+                            abstract["params"], self.cfg_file["vocab_size"])
 
         def build(key):
-            w = ref.init_weights(arch, key)
-            tok = w["embed"]["tok"]
-            w["embed"]["tok"] = jnp.pad(tok, ((0, rows - tok.shape[0]), (0, 0)))
-            params = jax.tree.map(lambda x, s: x.astype(s.dtype), w, abstract["params"])
+            w = init(arch, key)
+            params = jax.tree.map(lambda x, p, s: jnp.pad(x, p).astype(s.dtype),
+                                  w, pad, abstract["params"])
             zeros = lambda t: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), t)
             return {"params": params,
                     "opt": {"m": zeros(abstract["opt"]["m"]),
@@ -180,7 +177,7 @@ class Job:
                             "count": jnp.zeros((), jnp.int32)},
                     "step": jnp.zeros((), jnp.int32)}
 
-        made = jax.eval_shape(build, ref.seed_key(0))
+        made = jax.eval_shape(build, seed_key(0))
         if (jax.tree.structure(made) != jax.tree.structure(abstract)
                 or jax.tree.leaves(made) != jax.tree.leaves(abstract)):
             raise ValueError("the program's train state is not the params, "
@@ -201,7 +198,7 @@ class Job:
         Returns (state, readings, seconds of the first step)."""
         self.feed.reseed(seed)
         with self.context():
-            state = self._build(ref.seed_key(seed))
+            state = self._build(seed_key(seed))
             losses, grad = [], None
             t0 = time.perf_counter()
             for k in range(1, n + 1):
@@ -210,9 +207,9 @@ class Job:
                 if k == 1:
                     first_step_s = time.perf_counter() - t0
                     grad = [float(x) / (1 - self.tc.b1)
-                            for x in ref.leaf_norms(state["opt"]["m"])]
+                            for x in leaf_norms(state["opt"]["m"])]
                 losses.append(float(self.trainer.history[-1]["loss"]))
-            delta = ref.delta_norms(state["params"], self.arch, seed)
+            delta = self.ref.delta_norms(state["params"], self.arch, seed)
         return state, {"loss": losses, "grad": grad, "delta": delta}, first_step_s
 
     def window(self, state, clock: Clock) -> None:
@@ -249,11 +246,18 @@ class Job:
         batches = [tuple(x[blocks] for x in self.blocks(k, rows)) for k in range(n)]
         nb = batches[0][0].shape[0]
         nd = max(d for d in range(1, len(self.devices) + 1) if nb % d == 0)
-        return ref.follow(self.arch, self.optim, seed, batches,
-                          self.devices[:nd], prec)
+        return self.ref.follow(self.arch, self.optim, seed, batches,
+                               self.devices[:nd], prec)
 
     def close(self):
         self._tmp.cleanup()
+
+
+def read_trace(path: str, steps: int) -> dict:
+    """A traced window's device time (``trace.reduce``) and its device time
+    by the program's named scopes with the host's spans (``scopes.reduce``),
+    in one record."""
+    return {**scopes.reduce(path, steps), **trace_mod.reduce(path, steps)}
 
 
 def peak_bytes(devices) -> int | None:
@@ -294,8 +298,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, devices,
         "trace": None,
     }
     if trace:
-        rec["trace"] = trace_mod.reduce(trace_mod.find_xplane(trace_dir),
-                                        traffic["trace_steps"])
+        rec["trace"] = read_trace(trace_mod.find_xplane(trace_dir), traffic["trace_steps"])
         shutil.rmtree(trace_dir, ignore_errors=True)
     live = sum(x.nbytes for x in jax.live_arrays())
     log(f"bytes still live on the devices before the reference: {live}")

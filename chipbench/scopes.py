@@ -118,6 +118,13 @@ def reduce(path: str, steps: int, top: int = 3) -> dict:
     return out
 
 
+def scope_ms(reduced: dict | None, scope: str) -> float | None:
+    """Milliseconds per traced step of ``scope`` in a record that holds
+    ``reduce``'s keys, or None where it has none."""
+    seconds = (reduced or {}).get("scopes", {}).get(scope)
+    return 1e3 * seconds if seconds else None
+
+
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("xplane")

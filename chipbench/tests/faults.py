@@ -40,6 +40,9 @@ def plant(fault: str) -> None:
         train_step.mapi = dataclasses.make_dataclass("M", [])()
         train_step.mapi.get_api = halved
     elif fault == "no_exchange":
+        # under ``auto`` XLA inserts the all-reduce: the step body then runs
+        # per chip inside shard_map, as the manual modes do, and syncs nothing
+        train_step.MANUAL_ALGOS += ("auto",)
         train_step.sync_gradients = lambda grads, tc, mesh, ef, **kw: (grads, None)
     elif fault != "none":
         raise ValueError(fault)

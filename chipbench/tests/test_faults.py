@@ -16,7 +16,7 @@ import pytest
 
 HERE = Path(__file__).resolve().parent
 
-CASES = [("qwen2-dp4-sharded", f) for f in
+CASES = [(w, f) for w in ("qwen2-dp4-sharded", "qwen2-dp4-auto") for f in
          ("none", "state_unchanged", "half_batch", "no_exchange")]
 CASES += [(w, f) for w in ("qwen2-train", "granite-moe-train")
           for f in ("none", "state_unchanged", "half_batch")]

@@ -1,13 +1,19 @@
-"""Model FLOPs per token of both configurations against a count by hand."""
+"""Model FLOPs per token of both configurations, as the reference module
+each names counts them (``reference/decoder.py``), against a count by hand:
+the numbers the count gave when it was ``chipbench/flops.py``."""
 
 import json
 
-from chipbench import BENCH
-from chipbench.flops import active_params, train_flops_per_token
+from chipbench import BENCH, reference
+from chipbench.reference.decoder import active_params
 
 
 def _cfg(name):
     return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
+def train_flops_per_token(cfg_file, seq_len):
+    return reference.load(cfg_file).train_flops_per_token(cfg_file, seq_len)
 
 
 def test_qwen2_8_layers():

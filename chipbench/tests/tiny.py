@@ -8,18 +8,18 @@ from chipbench import BENCH, ROOT
 
 CONFIGS = {
     "qwen2-1.5b": {
-        "registry_id": "qwen2-1.5b-smoke", "hidden_size": 128,
-        "initializer_range": 0.02, "intermediate_size": 256,
+        "registry_id": "qwen2-1.5b-smoke", "reference": "decoder",
+        "hidden_size": 128, "initializer_range": 0.02, "intermediate_size": 256,
         "num_attention_heads": 4, "num_hidden_layers": 2,
         "num_key_value_heads": 2, "qkv_bias": True, "rms_norm_eps": 1e-6,
-        "rope_theta": 1e6, "vocab_size": 512},
+        "rope_theta": 1e6, "tie_word_embeddings": True, "vocab_size": 512},
     "granite-moe-1b-a400m": {
-        "registry_id": "granite-moe-1b-a400m-smoke", "hidden_size": 128,
-        "initializer_range": 0.02, "intermediate_size": 64,
+        "registry_id": "granite-moe-1b-a400m-smoke", "reference": "decoder",
+        "hidden_size": 128, "initializer_range": 0.02, "intermediate_size": 64,
         "num_attention_heads": 4, "num_hidden_layers": 2,
         "num_key_value_heads": 2, "qkv_bias": False, "rms_norm_eps": 1e-6,
-        "rope_theta": 1e4, "vocab_size": 512, "num_local_experts": 4,
-        "num_experts_per_tok": 2, "capacity_factor": 1.25,
+        "rope_theta": 1e4, "tie_word_embeddings": True, "vocab_size": 512,
+        "num_local_experts": 4, "num_experts_per_tok": 2, "capacity_factor": 1.25,
         "router_aux_loss_coef": 0.01},
 }
 
