@@ -20,6 +20,17 @@ class MoEConfig:
     n_shared: int = 0               # always-on shared experts (deepseek-v2)
     capacity_factor: float = 1.25   # dispatch capacity multiplier
     router_aux_weight: float = 1e-2
+    norm_topk_prob: bool = True     # renormalise the top-k gates to sum to 1
+    # the experts this chip holds (expert parallelism): n_held experts from
+    # held_from; 0 holds every expert.  The router still scores all
+    # n_experts; entries routed to an expert held elsewhere add nothing here.
+    held_from: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """(first, count) of the experts held here."""
+        return self.held_from, self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -27,10 +38,25 @@ class MLAConfig:
     """Multi-head Latent Attention (DeepSeek-V2)."""
 
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536  # None: queries projected uncompressed
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (``rope_scaling`` of
+    type "yarn"): rope frequencies blended between interpolated and original
+    over a ramp of frequency indices, and the softmax scale multiplied by
+    ``mscale(factor, mscale_all_dim) ** 2``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -70,6 +96,7 @@ class ModelConfig:
     pos_embed: str = "rope"          # "rope" | "learned" | "none"
     norm_eps: float = 1e-6
     rope_theta: float = 1e4
+    rope_scaling: YarnScaling | None = None
     tie_embeddings: bool = False
     dense_d_ff: int = 0              # FFN width of the first_k_dense layers
     moe: MoEConfig | None = None
@@ -201,7 +228,8 @@ def smoke_variant(cfg: ModelConfig, **over) -> ModelConfig:
         kw["moe"] = replace(cfg.moe, n_experts=4, top_k=2, d_expert=64,
                             n_shared=min(cfg.moe.n_shared, 1))
     if cfg.mla:
-        kw["mla"] = MLAConfig(kv_lora_rank=32, q_lora_rank=48,
+        kw["mla"] = MLAConfig(kv_lora_rank=32,
+                              q_lora_rank=48 if cfg.mla.q_lora_rank else None,
                               qk_nope_head_dim=32, qk_rope_head_dim=16,
                               v_head_dim=32)
     if cfg.ssm:

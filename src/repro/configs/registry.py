@@ -5,6 +5,7 @@ from __future__ import annotations
 from . import (
     deepseek_67b,
     deepseek_v2_236b,
+    deepseek_v2_lite,
     gemma_7b,
     granite_moe_1b,
     internvl2_1b,
@@ -27,6 +28,7 @@ _MODULES = {
     "zamba2-2.7b": zamba2_2_7b,
     "granite-moe-1b-a400m": granite_moe_1b,
     "deepseek-v2-236b": deepseek_v2_236b,
+    "deepseek-v2-lite": deepseek_v2_lite,
 }
 
 ARCH_IDS = tuple(_MODULES)

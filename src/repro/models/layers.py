@@ -25,7 +25,7 @@ from jax import lax
 from jax.experimental.pallas.ops.tpu import splash_attention as splash
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, YarnScaling
 from repro.runtime import tracing
 
 Init = jax.nn.initializers.normal
@@ -68,14 +68,44 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (DeepSeek-V2's ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, y: YarnScaling) -> jax.Array:
+    """DeepSeek-V2's YaRN frequencies: each frequency index i < head_dim/2
+    blends the original frequency and the one interpolated by ``factor``,
+    the interpolated one wholly from ``high`` up and the original one wholly
+    below ``low``, linearly between.  ``low`` and ``high`` are the indices
+    that turn ``beta_fast`` and ``beta_slow`` times over the original
+    context."""
+    def index(turns):
+        return (head_dim * math.log(y.original_max_position / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(index(y.beta_fast)), 0)
+    high = min(math.ceil(index(y.beta_slow)), head_dim - 1)
+    extra = rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / ((high - low) or 1e-3), 0.0, 1.0)
+    return extra / y.factor * ramp + extra * (1.0 - ramp)
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               scale: float = 1.0) -> jax.Array:
+               scale: float = 1.0, scaling: YarnScaling | None = None) -> jax.Array:
     """x: [B, S, H, D]; positions: [B, S] (int).  ``scale`` multiplies the
-    rotated x in f32, before its one cast back to x's dtype."""
+    rotated x in f32, before its one cast back to x's dtype.  ``scaling``
+    takes the frequencies and the cos/sin magnitude from YaRN."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # [D/2]
+    freqs = rope_freqs(d, theta) if scaling is None else yarn_freqs(d, theta, scaling)
     ang = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if scaling is not None:
+        mag = (yarn_mscale(scaling.factor, scaling.mscale)
+               / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if mag != 1.0:
+            cos, sin = cos * mag, sin * mag
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     if scale != 1.0:
@@ -388,10 +418,14 @@ def init_mla(key, cfg: ModelConfig, dtype) -> dict:
     h = cfg.n_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 7)
+    if m.q_lora_rank is None:
+        p = {"wq": _dense_init(ks[0], (cfg.d_model, h * qk), dtype)}
+    else:
+        p = {"wq_a": _dense_init(ks[0], (cfg.d_model, m.q_lora_rank), dtype),
+             "q_norm": jnp.ones((m.q_lora_rank,), dtype),
+             "wq_b": _dense_init(ks[1], (m.q_lora_rank, h * qk), dtype)}
     return {
-        "wq_a": _dense_init(ks[0], (cfg.d_model, m.q_lora_rank), dtype),
-        "q_norm": jnp.ones((m.q_lora_rank,), dtype),
-        "wq_b": _dense_init(ks[1], (m.q_lora_rank, h * qk), dtype),
+        **p,
         "wkv_a": _dense_init(ks[2], (cfg.d_model, m.kv_lora_rank + m.qk_rope_head_dim), dtype),
         "kv_norm": jnp.ones((m.kv_lora_rank,), dtype),
         "wkv_b": _dense_init(ks[3], (m.kv_lora_rank, h * (m.qk_nope_head_dim + m.v_head_dim)), dtype),
@@ -405,13 +439,25 @@ def _rms(x, scale, eps):
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk head size), times ``mscale(factor, mscale_all_dim) ** 2``
+    under YaRN, as DeepSeek-V2 scales its scores."""
+    m: MLAConfig = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_compress(p: dict, x: jax.Array, cfg: ModelConfig, positions: jax.Array):
     """Produce the compressed KV the cache stores: c_kv [B,S,r], k_rope [B,S,1,dr]."""
     m: MLAConfig = cfg.mla
     kv_a = _proj(x, p["wkv_a"])
     c_kv, k_rope = kv_a[..., : m.kv_lora_rank], kv_a[..., m.kv_lora_rank:]
     c_kv = _rms(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        scaling=cfg.rope_scaling)
     return c_kv, k_rope
 
 
@@ -423,14 +469,22 @@ def mla_expand_kv(p: dict, c_kv: jax.Array, cfg: ModelConfig):
     return kv[..., : m.qk_nope_head_dim], kv[..., m.qk_nope_head_dim:]
 
 
-def mla_queries(p: dict, x: jax.Array, cfg: ModelConfig, positions: jax.Array):
+def mla_queries(p: dict, x: jax.Array, cfg: ModelConfig, positions: jax.Array,
+                scale: float = 1.0):
+    """(q_nope, q_rope), each times ``scale`` in f32 before its one cast;
+    q_rope rotated.  Without query compression q is one projection."""
     m: MLAConfig = cfg.mla
     h = cfg.n_heads
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-    q = _proj(_rms(_proj(x, p["wq_a"]), p["q_norm"], cfg.norm_eps), p["wq_b"])
+    if m.q_lora_rank is None:
+        q = _proj(x, p["wq"])
+    else:
+        q = _proj(_rms(_proj(x, p["wq_a"]), p["q_norm"], cfg.norm_eps), p["wq_b"])
     q = q.reshape(*x.shape[:-1], h, qk)
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    if scale != 1.0:
+        q_nope = (q_nope.astype(jnp.float32) * scale).astype(q_nope.dtype)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, scale, cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -443,12 +497,38 @@ def mla_apply(
     cache: dict | None = None,         # {"c_kv": [B,Smax,r], "k_rope": [B,Smax,1,dr]}
     cache_index: jax.Array | int | None = None,
 ) -> tuple[jax.Array, dict | None]:
+    """Training runs the per-head K/V through the fused kernel on a TPU,
+    as :func:`attention_apply` does (one KV head per query head, q/k of
+    nope + rope width, v of its own); prefill through a cache runs
+    :func:`blocked_attention`, decode the absorbed latent path."""
     m: MLAConfig = cfg.mla
     b, s, _ = x.shape
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
-    q_nope, q_rope = mla_queries(p, x, cfg, positions)
+    scale = mla_softmax_scale(cfg)
     c_kv, k_rope = mla_compress(p, x, cfg, positions)
 
+    def full_kv(c_kv, k_rope):
+        k_nope, v = mla_expand_kv(p, c_kv, cfg)     # [B,Skv,H,dn], [B,Skv,H,dv]
+        k_full = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (*k_nope.shape[:-1], m.qk_rope_head_dim))],
+            axis=-1,
+        )
+        return k_full, v
+
+    def q_full(scale=1.0):
+        return jnp.concatenate(mla_queries(p, x, cfg, positions, scale), axis=-1)
+
+    if cache is None and (fused := _fused_route(b, s)) is not None:
+        # the kernel takes no scale: fold the softmax scale into q's f32 math
+        tracing.take_path("attention", "kernel")
+        k_full, v = full_kv(c_kv, k_rope)
+        out = lax.platform_dependent(
+            tpu=lambda: fused(q_full(scale), k_full, v),
+            default=lambda: blocked_attention(q_full(), k_full, v, causal=True,
+                                              scale=scale))
+        y = out.reshape(b, s, cfg.n_heads * m.v_head_dim) @ p["wo"].astype(x.dtype)
+        return y, None
+
+    q_nope, q_rope = mla_queries(p, x, cfg, positions)
     new_cache = None
     if cache is not None:
         ckv_c = lax.dynamic_update_slice_in_dim(
@@ -491,13 +571,9 @@ def mla_apply(
                          preferred_element_type=jnp.float32).astype(x.dtype)
         out = jnp.einsum("bhr,rhd->bhd", lat, w_v)[:, None]        # [B,1,H,dv]
     else:
-        k_nope, v = mla_expand_kv(p, c_kv_all, cfg)     # [B,Skv,H,dn], [B,Skv,H,dv]
-        k_full = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope_all, (*k_nope.shape[:-1], m.qk_rope_head_dim))],
-            axis=-1,
-        )
-        q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-        out = blocked_attention(q_full, k_full, v, causal=True, scale=scale,
+        k_full, v = full_kv(c_kv_all, k_rope_all)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        out = blocked_attention(q, k_full, v, causal=True, scale=scale,
                                 q_offset=0 if cache_index is None else cache_index)
     y = out.reshape(b, s, cfg.n_heads * m.v_head_dim) @ p["wo"].astype(x.dtype)
     return y, new_cache
@@ -539,13 +615,15 @@ def mlp_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 def init_moe(key, cfg: ModelConfig, dtype) -> dict:
     mo: MoEConfig = cfg.moe
+    held = mo.held[1]
     ks = jax.random.split(key, 5)
     p = {
         "router": _dense_init(ks[0], (cfg.d_model, mo.n_experts), dtype),
-        # stacked expert weights: [E, d, ff] / [E, ff, d] — EP shards dim 0
-        "w_gate": _dense_init(ks[1], (mo.n_experts, cfg.d_model, mo.d_expert), dtype),
-        "w_up": _dense_init(ks[2], (mo.n_experts, cfg.d_model, mo.d_expert), dtype),
-        "w_down": _dense_init(ks[3], (mo.n_experts, mo.d_expert, cfg.d_model), dtype),
+        # the held experts' stacked weights: [E, d, ff] / [E, ff, d] — EP
+        # shards dim 0
+        "w_gate": _dense_init(ks[1], (held, cfg.d_model, mo.d_expert), dtype),
+        "w_up": _dense_init(ks[2], (held, cfg.d_model, mo.d_expert), dtype),
+        "w_down": _dense_init(ks[3], (held, mo.d_expert, cfg.d_model), dtype),
     }
     if mo.n_shared:
         p["shared"] = init_mlp(ks[4], cfg, dtype, d_ff=mo.d_expert * mo.n_shared)
@@ -567,8 +645,12 @@ def _moe_groups(t: int) -> int:
     return g if g > 0 and t % g == 0 else 1
 
 
-def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
-    """Returns (output, aux_loss).  Grouped sort-based dispatch (GShard-style):
+def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, dict]:
+    """Returns (output, aux): ``aux["aux"]`` the load-balancing loss, and
+    where this chip holds a share of the experts, ``moe_routed``,
+    ``moe_held`` and ``moe_dropped``: the layer's routed entries (tokens
+    times top-k), those routed to a held expert, and those of them dropped
+    by capacity.  Grouped sort-based dispatch (GShard-style):
 
       tokens reshaped to [G, T_g] with G sharded over the DP axes -> per-group
       top-k -> per-group sort by expert -> position-in-expert -> scatter into
@@ -581,6 +663,10 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.A
     (data × model).  A globally-sorted variant was measured 20+ GiB/device
     worse (see EXPERIMENTS.md §Perf, hypothesis log).
 
+    With a held share (``MoEConfig.held``) the router, top-k, capacity and
+    aux loss stay over all ``n_experts``; only the held experts get slots,
+    and an entry routed to an expert held elsewhere adds nothing here.
+
     The expert FFNs are named scope ``ffn``; routing, dispatch, combine and
     the aux loss are ``moe_dispatch``.
     """
@@ -591,24 +677,27 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.A
     t = b * s
     g = _moe_groups(t)
     tg = t // g
-    e, k = mo.n_experts, mo.top_k
+    n_all, k = mo.n_experts, mo.top_k
+    h0, e = mo.held
+    share = e < n_all
     with jax.named_scope("moe_dispatch"):
         xt = pctx.constrain(x.reshape(g, tg, d), pctx.BATCH, None, None)
 
         logits = (xt @ p["router"].astype(x.dtype)).astype(jnp.float32)   # [G,Tg,E]
         probs = jax.nn.softmax(logits, axis=-1)
         weights, eids = lax.top_k(probs, k)                               # [G,Tg,k]
-        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+        if mo.norm_topk_prob:
+            weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
 
         # aux load-balancing loss (Switch-style, computed over all tokens)
         gi = jnp.arange(g)[:, None]
-        density = jnp.zeros((g, e), jnp.float32).at[
+        density = jnp.zeros((g, n_all), jnp.float32).at[
             jnp.broadcast_to(gi[..., None], eids.shape), eids].add(1.0)
         density = density.sum(0) / (t * k)
         router_prob = probs.mean((0, 1))
-        aux = e * jnp.sum(density * router_prob) * mo.router_aux_weight
+        aux = n_all * jnp.sum(density * router_prob) * mo.router_aux_weight
 
-        cap = int(mo.capacity_factor * k * tg / e) + 1                    # C per (group, expert)
+        cap = int(mo.capacity_factor * k * tg / n_all) + 1                # C per (group, expert)
         tgk = tg * k
 
         # ---- gather-only dispatch.  The obvious scatter formulation
@@ -617,16 +706,27 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.A
         # the 236B cell, see the §Perf hypothesis log); with the sort, every
         # expert's entries are a contiguous range, so slots can be *gathered*.
         flat_e = eids.reshape(g, tgk)                                     # [G,Tg*k]
+        if share:
+            # held experts renumbered 0..e-1; the rest take key e, sort
+            # last, and fall outside the counts (an out-of-range scatter
+            # index is dropped)
+            flat_e = jnp.where((flat_e >= h0) & (flat_e < h0 + e), flat_e - h0, e)
         order = jnp.argsort(flat_e, axis=-1)
         sorted_e = jnp.take_along_axis(flat_e, order, axis=-1)
         inv_order = jnp.argsort(order, axis=-1)                           # entry -> sorted pos
         counts = jnp.zeros((g, e), jnp.int32).at[
-            jnp.broadcast_to(gi, flat_e.shape), flat_e].add(1)            # tiny scatter
+            jnp.broadcast_to(gi, flat_e.shape), flat_e].add(1, mode="drop")  # tiny scatter
         seg_start = jnp.cumsum(counts, axis=-1) - counts                  # [G,E]
 
         # slot (e, c) reads sorted position seg_start[e] + c while c < counts[e]
         slot_src = seg_start[..., None] + jnp.arange(cap)[None, None]     # [G,E,C]
         slot_valid = jnp.arange(cap)[None, None] < counts[..., None]
+        if share:
+            # an empty slot reads an entry of its own rather than a clipped
+            # one: the gathers' backward then adds its zeros over many rows,
+            # where on one repeated row a scatter-add serialises
+            slot_src = jnp.where(slot_valid, slot_src,
+                                 jnp.arange(e * cap).reshape(e, cap) % tgk)
         slot_src = jnp.clip(slot_src, 0, tgk - 1).reshape(g, e * cap)
         tok_of = order // k                                               # [G,Tg*k]
         slot_tok = jnp.take_along_axis(tok_of, slot_src, axis=1)          # [G,E*C]
@@ -649,22 +749,46 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.A
         # combine: entry j (sorted) lives at flat slot sorted_e*C + pos; dropped
         # entries (pos >= C) are masked.  Un-sort via the inverse permutation and
         # fold k back into the token dim with a reshape+sum — no scatter.
+        if share:
+            elsewhere = sorted_e >= e
+            sorted_e = jnp.minimum(sorted_e, e - 1)
         pos_in_e = jnp.arange(tgk)[None] - jnp.take_along_axis(
             seg_start, sorted_e, axis=-1)                                 # [G,Tg*k]
         dropped = pos_in_e >= cap
         slot_of = sorted_e * cap + jnp.clip(pos_in_e, 0, cap - 1)
+        empty = dropped
+        if share:
+            # likewise each masked entry (most of them: 7 in 8 are routed
+            # elsewhere) reads a slot of its own, not its expert's last
+            empty = dropped | elsewhere
+            slot_of = jnp.where(empty, jnp.arange(tgk) % (e * cap), slot_of)
         y_sorted = jnp.take_along_axis(
             y_e.reshape(g, e * cap, d), slot_of[..., None], axis=1)
-        y_sorted = jnp.where(dropped[..., None], 0, y_sorted)
+        y_sorted = jnp.where(empty[..., None], 0, y_sorted)
         y_entries = jnp.take_along_axis(y_sorted, inv_order[..., None], axis=1)
         contrib = y_entries * weights.reshape(g, tgk)[..., None].astype(x.dtype)
         out = contrib.reshape(g, tg, k, d).sum(axis=2)                    # [G,Tg,d]
         out = pctx.constrain(out, pctx.BATCH, None, None)
+        aux = {"aux": aux}
+        if share:
+            held = ~elsewhere
+            aux.update(moe_routed=jnp.asarray(t * k, jnp.float32),
+                       moe_held=held.sum().astype(jnp.float32),
+                       moe_dropped=(dropped & held).sum().astype(jnp.float32))
 
     if mo.n_shared:
         with jax.named_scope("ffn"):
             out = out + mlp_apply(p["shared"], xt, cfg)
     return out.reshape(b, s, d), aux
+
+
+def moe_aux_zero(cfg: ModelConfig) -> dict:
+    """Zeros in the structure of ``moe_apply``'s aux for this configuration:
+    a dense layer's aux, and the sum over no layers."""
+    z = jnp.zeros((), jnp.float32)
+    if cfg.moe is None or cfg.moe.held[1] == cfg.moe.n_experts:
+        return {"aux": z}
+    return {"aux": z, "moe_routed": z, "moe_held": z, "moe_dropped": z}
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,9 @@
 """Decoder-only transformer family.
 
 Covers: deepseek-67b, qwen2-1.5b, qwen1.5-4b, gemma-7b (dense decoders),
-internvl2-1b (decoder + patch-embedding stub prepended), granite-moe and
-deepseek-v2-236b (MoE decoders, the latter with MLA attention and
-first-k-dense layers).
+internvl2-1b (decoder + patch-embedding stub prepended), granite-moe,
+deepseek-v2-236b and deepseek-v2-lite (MoE decoders, the DeepSeek ones with
+MLA attention, shared experts and first-k-dense layers).
 
 Layers are scan-stacked: every layer's params live in one pytree whose
 leaves carry a leading [L] axis, and the forward pass is a single
@@ -88,7 +88,7 @@ def _layer_fwd(p, x, cfg, positions, *, cache=None, cache_index=None,
                                              cache=cache, cache_index=cache_index)
     x = x + a
     h = L.norm_apply(p["ln2"], x, cfg)
-    aux = jnp.zeros((), jnp.float32)
+    aux = L.moe_aux_zero(cfg)
     if _use_moe(cfg, dense):
         # scopes its expert FFNs as "ffn" and the rest as "moe_dispatch"
         m, aux = L.moe_apply(p["moe"], h, cfg)
@@ -111,8 +111,10 @@ def forward(
     cache_index: int | jax.Array | None = None,
     remat: str = "full",
     window: int | None = None,
-) -> tuple[jax.Array, dict | None, jax.Array]:
-    """Returns (hidden [B,S,d], new_cache | None, aux_loss)."""
+) -> tuple[jax.Array, dict | None, dict]:
+    """Returns (hidden [B,S,d], new_cache | None, aux): ``aux["aux"]`` the
+    layers' load-balancing loss, and where the experts are a held share,
+    their entry counts summed over the layers (``layers.moe_apply``)."""
     b, s = tokens.shape
     base_pos = 0 if cache_index is None else cache_index
     with jax.named_scope("embed_head"):
@@ -130,7 +132,7 @@ def forward(
                          axis=0).astype(compute_dtype)
     x = pctx.constrain_acts(x)
 
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = L.moe_aux_zero(cfg)
 
     # unstacked dense-FFN layers first (deepseek-v2 first_k_dense)
     dense_caches = []
@@ -141,14 +143,14 @@ def forward(
                                         cache_index=cache_index, window=window,
                                         dense=True)
             dense_caches.append(ncache)
-            aux_total = aux_total + aux
+            aux_total = jax.tree.map(jnp.add, aux_total, aux)
 
     def body(carry, layer_in):
         xc, auxc = carry
         lp, lcache = layer_in
         xo, ncache, aux = _layer_fwd(lp, xc, cfg, positions, cache=lcache,
                                      cache_index=cache_index, window=window)
-        return (xo, auxc + aux), ncache
+        return (xo, jax.tree.map(jnp.add, auxc, aux)), ncache
 
     if remat == "full":
         body = jax.checkpoint(body)
@@ -195,7 +197,11 @@ def loss_fn(params, batch: dict, cfg: ModelConfig, *, compute_dtype=jnp.bfloat16
     with jax.named_scope("embed_head"):
         logits = logits_fn(params, hidden, cfg)
         loss = L.masked_xent(logits, labels)
-    return loss + aux, {"nll": loss, "aux": aux}
+    metrics = {"nll": loss, "aux": aux["aux"]}
+    if "moe_held" in aux:
+        metrics["moe_held_share"] = aux["moe_held"] / aux["moe_routed"]
+        metrics["moe_dropped_share"] = aux["moe_dropped"] / jnp.maximum(aux["moe_held"], 1.0)
+    return loss + aux["aux"], metrics
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=jnp.bfloat16) -> dict:

@@ -745,6 +745,8 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, mesh=None):
         loss, metrics, grads = _microbatched_grads(
             loss_fn, state["params"], batch, tc.microbatches,
             accum_dtype=_dtype(tc.grad_accum_dtype))
+        # the expert layer's counters of a held share (layers.moe_apply)
+        counters = {k: v for k, v in metrics.items() if k.startswith("moe_")}
         new_ef = None
         if tc.sync_algorithm in MANUAL_ALGOS:
             with jax.named_scope("grad_sync"):
@@ -752,6 +754,8 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, mesh=None):
                                                sync_plans=sync_plans,
                                                plan_codes=plan_codes)
                 loss = lax.pmean(loss, dp_axes_of(mesh))
+                if counters:
+                    counters = lax.pmean(counters, dp_axes_of(mesh))
         with jax.named_scope("optimizer"):
             lr = lr_fn(state["step"])
             params, opt, om = adamw_update(grads, state["opt"], state["params"],
@@ -759,7 +763,7 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, mesh=None):
         new_state = {"params": params, "opt": opt, "step": state["step"] + 1}
         if "ef" in state:
             new_state["ef"] = new_ef if new_ef is not None else state["ef"]
-        return new_state, {"loss": loss, "lr": lr, **om}
+        return new_state, {"loss": loss, "lr": lr, **om, **counters}
 
     if tc.sync_algorithm not in MANUAL_ALGOS:
         return step_body

@@ -11,7 +11,10 @@ Each stretch of a step runs in a host span named in
 counter: ``Trainer.compiles`` / ``compile_s``, and ``compiles`` in each
 ``history`` entry for its step. Once the step has compiled,
 ``Trainer.attention_paths`` says how many of its attention layers run the
-fused kernel and how many ``blocked_attention`` (logged at INFO).
+fused kernel and how many ``blocked_attention`` (logged at INFO). Where
+the experts are a held share, each ``history`` entry and log line carries
+the share of routed entries that went to a held expert and the share of
+those dropped by capacity (``MOE_COUNTERS``).
 """
 
 from __future__ import annotations
@@ -37,6 +40,10 @@ from .train_step import (
     train_state_specs)
 
 log = logging.getLogger("repro.trainer")
+
+# the step's counters of an expert layer that holds a share of the experts
+# (models.layers.moe_apply), logged with the loss
+MOE_COUNTERS = ("moe_held_share", "moe_dropped_share")
 
 
 @dataclass
@@ -242,7 +249,8 @@ class Trainer:
                     m = {k: float(jax.device_get(v)) for k, v in metrics.items()}
                     m.update(step=step, sec_per_step=dt, compiles=compiles)
                     self.history.append(m)
-                    log.info("step %d loss %.4f (%.2fs)", step, m["loss"], dt)
+                    moe = "".join(f", {k} {m[k]:.4f}" for k in MOE_COUNTERS if k in m)
+                    log.info("step %d loss %.4f%s (%.2fs)", step, m["loss"], moe, dt)
             with span("trainer.checkpoint", step=step):
                 if self._ckpt_requested:
                     self._ckpt_requested = False

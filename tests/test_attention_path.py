@@ -168,25 +168,74 @@ def test_attention_dispatch(case):
                                   np.asarray(jax.jit(parent)(), np.float32))
 
 
-@pytest.mark.parametrize("decode", [False, True])
-def test_mla_dispatch(decode):
-    """MLA never takes the kernel: its prefill and training attention run
-    blocked_attention, its decode the absorbed latent path."""
+@pytest.mark.parametrize("case", ["train", "decode", "serve"])
+def test_mla_dispatch(case):
+    """MLA's training attention takes the kernel, and off the TPU computes
+    exactly what blocked_attention computed before the kernel existed; its
+    decode takes the absorbed latent path; and serving through the latent
+    cache, prefill then decode, gives the full forward's logits with
+    DeepSeek-V2-Lite's YaRN (whose softmax scale every path must carry)."""
+    if case == "serve":
+        _mla_serve_matches_forward()
+        return
     cfg = registry.get("deepseek-v2-236b", smoke=True)
     p = L.init_mla(jax.random.key(2), cfg, jnp.float32)
-    b, s = 2, 1 if decode else 256
+    b, s = 2, 1 if case == "decode" else 256
     x = _rand((b, s, cfg.d_model), jnp.bfloat16)
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     kw = {}
-    if decode:
+    if case == "decode":
         m = cfg.mla
         kw = {"cache": {"c_kv": jnp.zeros((b, 64, m.kv_lora_rank), jnp.bfloat16),
                         "k_rope": jnp.zeros((b, 64, 1, m.qk_rope_head_dim), jnp.bfloat16)},
               "cache_index": 5}
     with tracing.PathCounter() as paths:
-        jax.jit(lambda: L.mla_apply(p, x, cfg, pos, **kw)[0])()
-    want = "decode" if decode else "blocked"
+        got = jax.jit(lambda: L.mla_apply(p, x, cfg, pos, **kw)[0])()
+    want = "decode" if case == "decode" else "kernel"
     assert dict(paths.counts) == {("attention", want): 1}
+    if case == "train":
+        def parent():
+            q = jnp.concatenate(L.mla_queries(p, x, cfg, pos), axis=-1)
+            c_kv, k_rope = L.mla_compress(p, x, cfg, pos)
+            k_nope, v = L.mla_expand_kv(p, c_kv, cfg)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_rope, (*k_nope.shape[:-1], cfg.mla.qk_rope_head_dim))], axis=-1)
+            out = L.blocked_attention(q, k, v, causal=True,
+                                      scale=L.mla_softmax_scale(cfg))
+            return out.reshape(b, s, -1) @ p["wo"].astype(x.dtype)
+
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(jax.jit(parent)(), np.float32))
+
+
+def _mla_serve_matches_forward():
+    """Prefill of 5 tokens then 3 decode steps through the latent cache
+    against the full forward's logits at those positions, in float32. The
+    experts' capacity holds every entry, so the number of tokens in a call
+    does not change which entries the experts keep."""
+    import dataclasses
+
+    from repro.models import api as mapi, transformer as T
+
+    cfg = registry.get("deepseek-v2-lite", smoke=True)
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, capacity_factor=float(cfg.moe.n_experts)))
+    assert cfg.rope_scaling is not None and cfg.mla.q_lora_rank is None
+    api = mapi.get_api(cfg, compute_dtype=jnp.float32, remat="none")
+    params = api.init(jax.random.key(4))
+    toks = jnp.asarray(RNG.integers(0, cfg.vocab_size, (2, 8)), jnp.int32)
+    hidden, _, _ = T.forward(params, toks, cfg, compute_dtype=jnp.float32,
+                             remat="none")
+    full = np.asarray(T.logits_fn(params, hidden, cfg))
+    cache = api.init_cache(2, 16, dtype=jnp.float32)
+    with tracing.PathCounter() as paths:
+        logits, cache = jax.jit(api.prefill)(params, {"tokens": toks[:, :5]}, cache)
+    assert paths.counts[("attention", "blocked")] == cfg.n_layers
+    np.testing.assert_allclose(np.asarray(logits), full[:, 4], rtol=2e-4, atol=2e-4)
+    decode = jax.jit(api.decode)
+    for t in range(5, 8):
+        logits, cache = decode(params, toks[:, t], jnp.asarray(t, jnp.int32), cache)
+        np.testing.assert_allclose(np.asarray(logits), full[:, t], rtol=2e-4, atol=2e-4)
 
 
 def test_path_counter_weights_the_layer_scan():

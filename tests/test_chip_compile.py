@@ -8,7 +8,8 @@ f32 bucket (the default ``TrainConfig.bucket_bytes``) and one length that
 is not a multiple of the block.  The fused attention kernel compiles inside
 the model's own loss gradient at the training cells' shapes (one layer
 each): Qwen2-1.5B at 4x2048 on one chip and on each chip of a v5e:2x2 under
-the trainer's ``shard_map``, Granite-MoE at 2x4096.
+the trainer's ``shard_map``, Granite-MoE at 2x4096, and DeepSeek-V2-Lite's
+latent attention (q/k 192, v 128, YaRN) at 4x4096 behind its dense layer.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -86,11 +87,14 @@ def test_dequant_add_compiles(one_chip, n):
 # ------------------------------------------------------------ attention
 
 # the training cells' configurations, one layer each, and rows a chip
-CELLS = {"qwen2-1.5b": (4, 2048), "granite-moe-1b-a400m": (2, 4096)}
+CELLS = {"qwen2-1.5b": (4, 2048), "granite-moe-1b-a400m": (2, 4096),
+         "deepseek-v2-lite": (4, 4096)}
 
 
 def _one_layer(name):
-    return dataclasses.replace(registry.get(name), n_layers=1)
+    """One layer of the scan, behind the leading dense layers if any."""
+    cfg = registry.get(name)
+    return dataclasses.replace(cfg, n_layers=cfg.first_k_dense + 1)
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -107,6 +111,27 @@ def test_attention_kernel_in_loss_grad(one_chip, name):
 
     # the layer's forward, its remat recompute and the backward: no fallback
     assert FUSED_KERNEL in _compiled_text(jax.jit(jax.grad(loss)), params, tokens)
+
+
+def test_canon_hlo_reads_kernels_without_their_source_lines(one_chip):
+    """A kernel's serialized body carries the file and line of each of its
+    ops: the same kernel called from two places compiles to different
+    bytes, and ``tools/canon_hlo.py`` prints both as one program."""
+    import importlib.util
+    from pathlib import Path
+
+    from repro.models.layers import fused_causal_attention
+
+    path = Path(__file__).resolve().parents[1] / "tools" / "canon_hlo.py"
+    spec = importlib.util.spec_from_file_location("canon_hlo", path)
+    canon_hlo = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(canon_hlo)
+    x = _spec((1, 256, 2, 128), jnp.bfloat16, one_chip)
+    first = _compiled_text(jax.jit(lambda q, k, v: fused_causal_attention(q, k, v)), x, x, x)
+    second = _compiled_text(jax.jit(
+        lambda q, k, v: fused_causal_attention(q, k, v)), x, x, x)
+    assert first != second
+    assert canon_hlo.canon(first) == canon_hlo.canon(second)
 
 
 @pytest.mark.parametrize("sync", ["planned_sharded", "auto"])

@@ -109,6 +109,7 @@ def test_param_counts_match_published():
         "qwen1.5-4b": (4e9, 0.1), "gemma-7b": (8.5e9, 0.05),
         "whisper-medium": (0.77e9, 0.1), "zamba2-2.7b": (2.7e9, 0.15),
         "granite-moe-1b-a400m": (1.3e9, 0.1), "deepseek-v2-236b": (236e9, 0.03),
+        "deepseek-v2-lite": (15.7e9, 0.03),
     }
     for arch, (target, tol) in expected.items():
         n = mapi.param_count(registry.get(arch))
@@ -117,6 +118,6 @@ def test_param_counts_match_published():
 
 def test_all_cells_enumerate():
     cells = registry.cells()
-    assert len(cells) == 32  # 10 archs x 3 shapes + 2 sub-quadratic long_500k
+    assert len(cells) == 35  # 11 archs x 3 shapes + 2 sub-quadratic long_500k
     skipped = [c for c in registry.cells(include_skipped=True) if c[2]]
-    assert len(skipped) == 8
+    assert len(skipped) == 9

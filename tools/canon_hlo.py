@@ -4,13 +4,18 @@
     python tools/canon_hlo.py <module>.after_optimizations.txt
 
 Drops each instruction's ``metadata={...}`` (op names, source lines) and
-the stack-frame tables, and renames every instruction, computation and
-parameter by the order of its first appearance: XLA numbers them from a
-counter that metadata-only changes to the source can move. Two dumps print
-the same text exactly when they hold the same operations, in the same
-order, on the same operands.
+the stack-frame tables, prints each Pallas kernel's serialized Mosaic body
+as MLIR text without its source locations (a kernel's bytes carry the file
+and line of every op, so a line moved in the file that calls it changes
+them), and renames every instruction, computation and parameter by the
+order of its first appearance: XLA numbers them from a counter that
+metadata-only changes to the source can move. Two dumps print the same
+text exactly when they hold the same operations, in the same order, on the
+same operands.
 """
 
+import base64
+import json
 import re
 import sys
 
@@ -19,6 +24,18 @@ BODY = re.compile(r"^(ENTRY |%|HloModule )")
 METADATA = re.compile(r", metadata=\{[^}]*\}")
 # %name anywhere, or a parameter's name in a computation's signature
 NAME = re.compile(r"%([\w.\-]+)|(?<=[(\s])([\w.\-]+)(?=: )")
+KERNEL_BODY = re.compile(r'"body":"([A-Za-z0-9+/=]+)"')
+
+
+def _kernel_body(m) -> str:
+    from jaxlib.mlir import ir
+
+    with ir.Context() as ctx:
+        # the body is serialized with Mosaic's dialects left unregistered
+        ctx.allow_unregistered_dialects = True
+        module = ir.Module.parse(base64.b64decode(m.group(1)))
+        text = module.operation.get_asm(enable_debug_info=False)
+    return '"body":' + json.dumps(text)
 
 
 def canon(text: str) -> str:
@@ -33,7 +50,8 @@ def canon(text: str) -> str:
         new = names.setdefault(m.group(1) or m.group(2), f"v{len(names)}")
         return "%" + new if m.group(1) else new
 
-    return NAME.sub(rename, METADATA.sub("", "\n".join(lines))) + "\n"
+    text = KERNEL_BODY.sub(_kernel_body, METADATA.sub("", "\n".join(lines)))
+    return NAME.sub(rename, text) + "\n"
 
 
 if __name__ == "__main__":
