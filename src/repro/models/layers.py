@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -645,6 +646,65 @@ def _moe_groups(t: int) -> int:
     return g if g > 0 and t % g == 0 else 1
 
 
+class SlotMaps(NamedTuple):
+    """One dispatch's int maps between the experts' S slots and N rows of m
+    entries each (``moe_apply``: a token and its k routed entries).  Each
+    valid slot holds one valid entry and each valid entry sits in one
+    valid slot."""
+
+    slot_row: jax.Array      # [G,S] int32: the row each slot reads
+    slot_valid: jax.Array    # [G,S] bool: the slot holds an entry
+    row_slot: jax.Array      # [G,N,m] int32: the slot of each of a row's entries
+    row_valid: jax.Array     # [G,N,m] bool: the entry sits in a slot
+
+
+def _take_rows(src: jax.Array, idx: jax.Array) -> jax.Array:
+    """[G,N,d] rows at [G,M] in-bounds indices -> [G,M,d]."""
+    return jnp.take_along_axis(src, idx[..., None], axis=1, mode="promise_in_bounds")
+
+
+def _gather_slots(x: jax.Array, maps: SlotMaps) -> jax.Array:
+    return jnp.where(maps.slot_valid[..., None], _take_rows(x, maps.slot_row), 0)
+
+
+def _sum_to_tokens(y: jax.Array, maps: SlotMaps) -> jax.Array:
+    g, n, m = maps.row_slot.shape
+    ys = _take_rows(y, maps.row_slot.reshape(g, n * m))
+    ys = jnp.where(maps.row_valid.reshape(g, n * m, 1), ys, 0)
+    return ys.reshape(g, n, m, -1).sum(axis=2)
+
+
+@jax.custom_vjp
+def gather_slots(x: jax.Array, maps: SlotMaps) -> jax.Array:
+    """[G,N,d] rows -> [G,S,d] slots: each valid slot reads its row, an
+    empty slot is zero.  The dispatch; its backward is ``sum_to_tokens``."""
+    return _gather_slots(x, maps)
+
+
+@jax.custom_vjp
+def sum_to_tokens(y: jax.Array, maps: SlotMaps) -> jax.Array:
+    """[G,S,d] slots -> [G,N,d] rows: each row the sum of its valid
+    entries' slots, gathered and summed over m: the exact transpose of
+    ``gather_slots``.  The combine; its backward is ``gather_slots``, so
+    autodiff writes no scatter-add of d-wide rows."""
+    return _sum_to_tokens(y, maps)
+
+
+def _gather_slots_bwd(maps: SlotMaps, ct: jax.Array):
+    """The combine of the slots' cotangent, replicated over 'model' first
+    as the forward's combine reads them (``moe_apply``): on an EP mesh a
+    gather from slots sharded over 'model' would all-reduce all N*m rows."""
+    from repro.parallel import context as pctx
+
+    ct = pctx.constrain(ct, pctx.BATCH, None, None)
+    return _sum_to_tokens(ct, maps), None
+
+
+gather_slots.defvjp(lambda x, maps: (_gather_slots(x, maps), maps), _gather_slots_bwd)
+sum_to_tokens.defvjp(lambda y, maps: (_sum_to_tokens(y, maps), maps),
+                     lambda maps, ct: (_gather_slots(ct, maps), None))
+
+
 def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, dict]:
     """Returns (output, aux): ``aux["aux"]`` the load-balancing loss, and
     where this chip holds a share of the experts, ``moe_routed``,
@@ -653,10 +713,12 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, dict]
     by capacity.  Grouped sort-based dispatch (GShard-style):
 
       tokens reshaped to [G, T_g] with G sharded over the DP axes -> per-group
-      top-k -> per-group sort by expert -> position-in-expert -> scatter into
-      [G, E, C_g, d] slots (per-group capacity, overflow dropped) -> expert
-      FFN einsum contracted over d with E sharded over 'model' (EP) -> gather
-      back with routing weights.
+      top-k -> per-group sort by expert -> position-in-expert -> int maps
+      between slots and entries -> tokens gathered into [G, E, C_g, d] slots
+      (per-group capacity, overflow dropped; ``gather_slots``) -> expert FFN
+      einsum contracted over d with E sharded over 'model' (EP) -> slots
+      times routing weights summed back into their tokens
+      (``sum_to_tokens``, the dispatch's transpose).
 
     Every gather/scatter carries the G batch dim, so GSPMD keeps dispatch
     local per data shard; the [G, E, C, *] buffers are 2-D sharded
@@ -700,11 +762,14 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, dict]
         cap = int(mo.capacity_factor * k * tg / n_all) + 1                # C per (group, expert)
         tgk = tg * k
 
-        # ---- gather-only dispatch.  The obvious scatter formulation
-        # (slot_buf.at[g, e, c].set(tokens)) makes GSPMD's scatter partitioner
-        # replicate both operands with full-size all-reduces (+95 GiB/device on
-        # the 236B cell, see the §Perf hypothesis log); with the sort, every
-        # expert's entries are a contiguous range, so slots can be *gathered*.
+        # ---- dispatch and combine as one transpose pair of slot gathers.  The
+        # obvious scatter formulation (slot_buf.at[g, e, c].set(tokens)) makes
+        # GSPMD's scatter partitioner replicate both operands with full-size
+        # all-reduces (+95 GiB/device on the 236B cell, see the §Perf
+        # hypothesis log); with the sort, every expert's entries are a
+        # contiguous range, so slots can be *gathered*.  The sort is composed
+        # into int maps (slot -> entry, entry -> slot) and no [G,Tg*k,d]
+        # array is permuted: each gather's backward is the other's forward.
         flat_e = eids.reshape(g, tgk)                                     # [G,Tg*k]
         if share:
             # held experts renumbered 0..e-1; the rest take key e, sort
@@ -712,27 +777,28 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, dict]
             # index is dropped)
             flat_e = jnp.where((flat_e >= h0) & (flat_e < h0 + e), flat_e - h0, e)
         order = jnp.argsort(flat_e, axis=-1)
-        sorted_e = jnp.take_along_axis(flat_e, order, axis=-1)
         inv_order = jnp.argsort(order, axis=-1)                           # entry -> sorted pos
         counts = jnp.zeros((g, e), jnp.int32).at[
             jnp.broadcast_to(gi, flat_e.shape), flat_e].add(1, mode="drop")  # tiny scatter
         seg_start = jnp.cumsum(counts, axis=-1) - counts                  # [G,E]
 
-        # slot (e, c) reads sorted position seg_start[e] + c while c < counts[e]
-        slot_src = seg_start[..., None] + jnp.arange(cap)[None, None]     # [G,E,C]
-        slot_valid = jnp.arange(cap)[None, None] < counts[..., None]
+        # slot (e, c) holds sorted position seg_start[e] + c while c <
+        # counts[e]; an entry sits at c = its sorted position minus its
+        # expert's seg_start, and is kept while c < C and its expert is held
+        slot_valid = (jnp.arange(cap)[None, None] < counts[..., None]).reshape(g, e * cap)
+        slot_src = jnp.clip(seg_start[..., None] + jnp.arange(cap)[None, None],
+                            0, tgk - 1).reshape(g, e * cap)
+        slot_ent = jnp.take_along_axis(order, slot_src, axis=1)           # [G,E*C]
+        ent_e = jnp.minimum(flat_e, e - 1)
+        pos_in_e = inv_order - jnp.take_along_axis(seg_start, ent_e, axis=1)
+        kept = pos_in_e < cap
         if share:
-            # an empty slot reads an entry of its own rather than a clipped
-            # one: the gathers' backward then adds its zeros over many rows,
-            # where on one repeated row a scatter-add serialises
-            slot_src = jnp.where(slot_valid, slot_src,
-                                 jnp.arange(e * cap).reshape(e, cap) % tgk)
-        slot_src = jnp.clip(slot_src, 0, tgk - 1).reshape(g, e * cap)
-        tok_of = order // k                                               # [G,Tg*k]
-        slot_tok = jnp.take_along_axis(tok_of, slot_src, axis=1)          # [G,E*C]
-        xs = jnp.take_along_axis(xt, slot_tok[..., None], axis=1)         # [G,E*C,d]
-        slot_buf = jnp.where(slot_valid.reshape(g, e * cap, 1), xs, 0)
-        slot_buf = slot_buf.reshape(g, e, cap, d)
+            held = flat_e < e
+            kept &= held
+        ent_slot = jnp.where(kept, ent_e * cap + pos_in_e, 0)             # [G,Tg*k]
+        to_tokens = SlotMaps(slot_ent // k, slot_valid, ent_slot.reshape(g, tg, k),
+                             kept.reshape(g, tg, k))
+        slot_buf = gather_slots(xt, to_tokens).reshape(g, e, cap, d)
         slot_buf = pctx.constrain(slot_buf, pctx.BATCH, pctx.MODEL, None, None)
 
     with jax.named_scope("ffn"):
@@ -743,38 +809,21 @@ def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, dict]
         y_e = jnp.einsum("gecf,efd->gecd", h, p["w_down"].astype(x.dtype))
     with jax.named_scope("moe_dispatch"):
         # replicate the (small) expert outputs over 'model' for the local
-        # combine-gather — this reshard is the EP "return" all-to-all
+        # combine — this reshard is the EP "return" all-to-all
         y_e = pctx.constrain(y_e, pctx.BATCH, None, None, None)
 
-        # combine: entry j (sorted) lives at flat slot sorted_e*C + pos; dropped
-        # entries (pos >= C) are masked.  Un-sort via the inverse permutation and
-        # fold k back into the token dim with a reshape+sum — no scatter.
-        if share:
-            elsewhere = sorted_e >= e
-            sorted_e = jnp.minimum(sorted_e, e - 1)
-        pos_in_e = jnp.arange(tgk)[None] - jnp.take_along_axis(
-            seg_start, sorted_e, axis=-1)                                 # [G,Tg*k]
-        dropped = pos_in_e >= cap
-        slot_of = sorted_e * cap + jnp.clip(pos_in_e, 0, cap - 1)
-        empty = dropped
-        if share:
-            # likewise each masked entry (most of them: 7 in 8 are routed
-            # elsewhere) reads a slot of its own, not its expert's last
-            empty = dropped | elsewhere
-            slot_of = jnp.where(empty, jnp.arange(tgk) % (e * cap), slot_of)
-        y_sorted = jnp.take_along_axis(
-            y_e.reshape(g, e * cap, d), slot_of[..., None], axis=1)
-        y_sorted = jnp.where(empty[..., None], 0, y_sorted)
-        y_entries = jnp.take_along_axis(y_sorted, inv_order[..., None], axis=1)
-        contrib = y_entries * weights.reshape(g, tgk)[..., None].astype(x.dtype)
-        out = contrib.reshape(g, tg, k, d).sum(axis=2)                    # [G,Tg,d]
+        # combine: each slot's output times its entry's routing weight, summed
+        # into its token; dropped entries and empty slots add nothing
+        w_slot = jnp.where(slot_valid, jnp.take_along_axis(
+            weights.reshape(g, tgk), slot_ent, axis=1), 0)[..., None]     # [G,E*C,1]
+        out = sum_to_tokens(y_e.reshape(g, e * cap, d) * w_slot.astype(x.dtype),
+                            to_tokens)                                    # [G,Tg,d]
         out = pctx.constrain(out, pctx.BATCH, None, None)
         aux = {"aux": aux}
         if share:
-            held = ~elsewhere
             aux.update(moe_routed=jnp.asarray(t * k, jnp.float32),
                        moe_held=held.sum().astype(jnp.float32),
-                       moe_dropped=(dropped & held).sum().astype(jnp.float32))
+                       moe_dropped=(held & ~kept).sum().astype(jnp.float32))
 
     if mo.n_shared:
         with jax.named_scope("ffn"):

@@ -9,6 +9,7 @@ The spans are read back from a CPU profile with the benchmark's reader
 """
 
 import functools
+import gc
 import glob
 import importlib.util
 import json
@@ -224,6 +225,9 @@ def test_compile_seconds_count_nested_traces_once():
     listener = lambda event, secs, **kw: events.append((event, secs))
     jax.monitoring.register_event_duration_secs_listener(listener)
     try:
+        # a full collection of earlier tests' garbage inside the window would
+        # add to the wall time and to no event
+        gc.collect()
         t0 = time.time()                         # the clock JAX stamps them with
         with counter:
             f(x).block_until_ready()
